@@ -99,6 +99,7 @@ from repro.scenarios.engine import CampaignEngine
 from repro.scenarios.montecarlo import params_from_scenario
 from repro.strategies import names as strategy_names
 from repro.telemetry import registry as detector_registry
+from repro.utils.backend import use_compile_cache
 from repro.workloads import registry as workload_registry
 
 PAPER_SCENARIOS = ("table1_periodic", "table1_random", "table2_random")
@@ -866,6 +867,7 @@ def main(argv=None):
     ap.add_argument("--seeds", type=int, default=2000, help="Monte-Carlo trials")
     ap.add_argument("--dry-run", action="store_true", help="tiny counts, no asserts")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     n_seeds = 64 if args.dry_run else max(args.seeds, 1000)
     micro = measure_micro("placentia", n_nodes=4)

@@ -33,9 +33,9 @@ five invariant families:
     time-valued names carry the ``_s`` suffix and seconds never mix with
     other unit suffixes under ``+``/``-``.
 ``dtype-x64``
-    replay-kernel modules (built under ``enable_x64``) and Pallas kernel
-    modules construct arrays with explicit dtypes, and the x64 modules
-    carry no 32-bit float literals.
+    replay-kernel modules (built under ``repro.utils.backend.x64``) and
+    Pallas kernel modules construct arrays with explicit dtypes, and the
+    x64 modules carry no 32-bit float literals.
 
 Run it as ``python -m repro.analysis src/`` (text report, nonzero exit
 on findings) or ``--json`` for the machine-readable record CI uploads.

@@ -3,7 +3,7 @@ modules; no 32-bit float literals in the x64 modules.
 
 The replay kernel's parity contract is *float64 arithmetic, identical to
 the engine's* — it is built and invoked under
-``jax.experimental.enable_x64``. A dtype-less ``jnp.zeros(H)`` there is
+:func:`repro.utils.backend.x64`. A dtype-less ``jnp.zeros(H)`` there is
 an accident waiting for the fleet-scale rewrite: the moment the kernel
 is constructed outside the x64 context (or a tile is built under
 ``shard_map`` with default promotion), the silent f32 default shears the
@@ -14,11 +14,9 @@ compiler); the f32-literal check applies only to the x64 modules, where
 ``np.float32`` is either a bug or a deliberate engine-fidelity constant
 (mark those with ``# repro: ignore[dtype-x64]``).
 
-Scope: a module is an **x64 module** if it imports
-``jax.experimental.enable_x64`` (the kernel's own discipline marker) and
-a **kernel module** if it imports Pallas; path patterns in
-``X64_PATTERNS`` / ``KERNEL_PATTERNS`` extend the net to modules that
-delegate the context handling.
+Scope: a module is an **x64 module** if it imports the x64 helper
+(``X64_HELPER``, the kernel's own discipline marker) and a **kernel
+module** if it imports Pallas or sits under ``KERNEL_PATTERNS``.
 """
 from __future__ import annotations
 
@@ -44,14 +42,9 @@ CONSTRUCTORS = {
 #: 32/16-bit float dtypes that must not appear in x64 modules
 NARROW_FLOATS = {"float32", "float16", "bfloat16"}
 
-X64_PATTERNS = ("scenarios/trajectory.py",)
+#: importing this marks a module as an x64 module
+X64_HELPER = "repro.utils.backend.x64"
 KERNEL_PATTERNS = ("/kernels/", "kernels/")
-
-
-def _imports_enable_x64(mod: ModuleSource) -> bool:
-    return "enable_x64" in mod.import_aliases().values() or any(
-        v.endswith(".enable_x64") for v in mod.import_aliases().values()
-    )
 
 
 def _imports_pallas(mod: ModuleSource) -> bool:
@@ -62,10 +55,9 @@ def _imports_pallas(mod: ModuleSource) -> bool:
 
 def _mode(mod: ModuleSource) -> Optional[str]:
     """"x64" | "kernel" | None — which check set applies."""
-    rel = mod.rel
-    if any(rel.endswith(p) or p in rel for p in X64_PATTERNS) or _imports_enable_x64(mod):
+    if X64_HELPER in mod.import_aliases().values():
         return "x64"
-    if any(p in "/" + rel for p in KERNEL_PATTERNS) or _imports_pallas(mod):
+    if any(p in "/" + mod.rel for p in KERNEL_PATTERNS) or _imports_pallas(mod):
         return "kernel"
     return None
 
@@ -107,7 +99,7 @@ class DtypeX64Rule(Rule):
                                 f"dtype-less `{dotted(node.func)}(...)` in an "
                                 f"{mode} module — pass an explicit dtype so the "
                                 f"array's precision survives outside the "
-                                f"enable_x64 context",
+                                f"x64 context",
                             )
                         )
                 elif mode == "x64" and isinstance(node, ast.Attribute):

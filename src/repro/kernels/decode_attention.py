@@ -6,7 +6,10 @@ state in scratch — one pass over K and V, fp32 accumulation, ring-buffer
 validity via the kpos array (matching the model's cache semantics:
 kpos >= 0, kpos <= pos, and optionally kpos > pos - window).
 
-Grid: (batch, q_heads, n_k_blocks); k-block axis iterates sequentially.
+Grid: (batch, kv_heads, n_k_blocks); each step scores the kv head's whole
+query group (the H // K query heads that share it) against one cache tile,
+so the query block is (group, hd) and meets the TPU's (8, 128) tiling rule
+as a full dimension. The k-block axis iterates sequentially.
 """
 from __future__ import annotations
 
@@ -17,12 +20,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.utils.backend import matmul_precision, pallas_interpret
+
 NEG_INF = -1.0e30
 
 
 def _decode_kernel(
     q_ref, k_ref, v_ref, kpos_ref, pos_ref, o_ref, m_scr, l_scr, acc_scr,
-    *, n_kb, window, scale,
+    *, n_kb, window, scale, precision,
 ):
     kb = pl.program_id(2)
 
@@ -32,34 +37,41 @@ def _decode_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, 0].astype(jnp.float32)  # (hd,)
+    q = q_ref[0, 0].astype(jnp.float32)  # (g, hd): the kv head's query group
     k = k_ref[0, 0].astype(jnp.float32)  # (bk, hd)
     v = v_ref[0, 0].astype(jnp.float32)
-    kpos = kpos_ref[0, 0]  # (bk,) int32
+    kpos = kpos_ref[0]  # (1, bk) int32
     pos = pos_ref[0]  # scalar int32
 
-    s = jnp.sum(k * q[None, :], axis=-1) * scale  # (bk,)
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), precision=precision,
+        preferred_element_type=jnp.float32,
+    ) * scale  # (g, bk)
     valid = (kpos >= 0) & (kpos <= pos)
     if window:
         valid = valid & (kpos > pos - window)
     s = jnp.where(valid, s, NEG_INF)
 
-    m_prev = m_scr[0]
-    m_new = jnp.maximum(m_prev, jnp.max(s))
+    m_prev = m_scr[...]  # (g, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
     p = jnp.exp(s - m_new)
-    l_scr[0] = l_scr[0] * alpha + jnp.sum(p)
-    acc_scr[...] = acc_scr[...] * alpha + jnp.sum(p[:, None] * v, axis=0)[None]
-    m_scr[0] = m_new
+    l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())), precision=precision,
+        preferred_element_type=jnp.float32,
+    )
+    m_scr[...] = m_new
 
     @pl.when(kb == n_kb - 1)
     def _finalize():
-        o_ref[0, 0] = (acc_scr[0] / jnp.maximum(l_scr[0], 1e-30)).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
 
 
-def flash_decode(q, k, v, kpos, pos, *, window=0, block_k=512, interpret=True):
+def flash_decode(q, k, v, kpos, pos, *, window=0, block_k=512, interpret=None):
     """q: (B, H, hd) one token per row; k/v: (B, K, S, hd) cache;
     kpos: (B, S) int32 cache positions (-1 = empty); pos: scalar int32.
+    ``interpret=None`` compiles on the TPU and interprets elsewhere.
 
     Returns (B, H, hd)."""
     B, H, hd = q.shape
@@ -73,26 +85,30 @@ def flash_decode(q, k, v, kpos, pos, *, window=0, block_k=512, interpret=True):
     scale = 1.0 / (hd ** 0.5)
     pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (1,))
 
-    kernel = functools.partial(_decode_kernel, n_kb=n_kb, window=window, scale=scale)
-    return pl.pallas_call(
+    kernel = functools.partial(
+        _decode_kernel, n_kb=n_kb, window=window, scale=scale,
+        precision=matmul_precision(q.dtype),
+    )
+    out = pl.pallas_call(
         kernel,
-        grid=(B, H, n_kb),
+        grid=(B, K, n_kb),
         in_specs=[
-            pl.BlockSpec((1, 1, hd), lambda b, h, j: (b, h, 0)),
-            pl.BlockSpec((1, 1, bk, hd), lambda b, h, j: (b, h // g, j, 0)),
-            pl.BlockSpec((1, 1, bk, hd), lambda b, h, j: (b, h // g, j, 0)),
+            pl.BlockSpec((1, 1, g, hd), lambda b, h, j: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, bk, hd), lambda b, h, j: (b, h, j, 0)),
+            pl.BlockSpec((1, 1, bk, hd), lambda b, h, j: (b, h, j, 0)),
             pl.BlockSpec((1, 1, bk), lambda b, h, j: (b, 0, j)),
             pl.BlockSpec((1,), lambda b, h, j: (0,)),
         ],
-        out_specs=pl.BlockSpec((1, 1, hd), lambda b, h, j: (b, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, g, hd), lambda b, h, j: (b, h, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, K, g, hd), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1, hd), jnp.float32),
+            pltpu.VMEM((g, 1), jnp.float32),
+            pltpu.VMEM((g, 1), jnp.float32),
+            pltpu.VMEM((g, hd), jnp.float32),
         ],
-        interpret=interpret,
-    )(q, k, v, kpos.reshape(B, 1, S), pos_arr)
+        interpret=pallas_interpret(interpret),
+    )(q.reshape(B, K, g, hd), k, v, kpos.reshape(B, 1, S), pos_arr)
+    return out.reshape(B, H, hd)
 
 
 def flash_decode_ref(q, k, v, kpos, pos, *, window=0):

@@ -20,12 +20,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.utils.backend import matmul_precision, pallas_interpret
+
 NEG_INF = -1.0e30
 
 
 def _flash_kernel(
     q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
-    *, block_q, block_k, n_kb, causal, window, scale,
+    *, block_q, block_k, n_kb, causal, window, scale, precision,
 ):
     kb = pl.program_id(3)
     qb = pl.program_id(2)
@@ -41,7 +43,8 @@ def _flash_kernel(
     v = v_ref[0, 0].astype(jnp.float32)  # (bk, hd)
 
     s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        q, k, (((1,), (1,)), ((), ())), precision=precision,
+        preferred_element_type=jnp.float32,
     ) * scale  # (bq, bk)
 
     qpos = qb * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
@@ -60,7 +63,8 @@ def _flash_kernel(
     p = jnp.exp(s - m_new[:, None])
     l_new = l_prev * alpha + jnp.sum(p, axis=1)
     acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        p, v, (((1,), (0,)), ((), ())), precision=precision,
+        preferred_element_type=jnp.float32,
     )
     m_scr[...] = m_new
     l_scr[...] = l_new
@@ -73,9 +77,10 @@ def _flash_kernel(
 
 
 def flash_attention(
-    q, k, v, *, causal=True, window=0, block_q=128, block_k=128, interpret=True
+    q, k, v, *, causal=True, window=0, block_q=128, block_k=128, interpret=None
 ):
     """q: (B, H, S, hd); k/v: (B, K, S, hd) with H % K == 0. Returns (B,H,S,hd).
+    ``interpret=None`` compiles on the TPU and interprets elsewhere.
 
     block sizes must divide S (pick S-sized blocks for short sequences)."""
     B, H, S, hd = q.shape
@@ -95,6 +100,7 @@ def flash_attention(
         causal=causal,
         window=window,
         scale=scale,
+        precision=matmul_precision(q.dtype),
     )
     return pl.pallas_call(
         kernel,
@@ -111,5 +117,5 @@ def flash_attention(
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(q, k, v)

@@ -18,28 +18,22 @@ from repro.kernels.rglru import rglru_scan as rglru_pallas
 from repro.kernels.rwkv6 import wkv6 as wkv6_pallas
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("causal", "window", "impl"))
 def attention(q, k, v, *, causal=True, window=0, impl="pallas"):
     if impl == "pallas":
-        return flash_attention(
-            q, k, v, causal=causal, window=window, interpret=not _on_tpu()
-        )
+        return flash_attention(q, k, v, causal=causal, window=window)
     return ref.attention_ref(q, k, v, causal=causal, window=window)
 
 
 @functools.partial(jax.jit, static_argnames=("impl",))
 def wkv6(r, k, v, wlog, u, state, *, impl="pallas"):
     if impl == "pallas":
-        return wkv6_pallas(r, k, v, wlog, u, state, interpret=not _on_tpu())
+        return wkv6_pallas(r, k, v, wlog, u, state)
     return ref.wkv6_ref(r, k, v, wlog, u, state)
 
 
 @functools.partial(jax.jit, static_argnames=("impl",))
 def rglru(log_a, m, h0, *, impl="pallas"):
     if impl == "pallas":
-        return rglru_pallas(log_a, m, h0, interpret=not _on_tpu())
+        return rglru_pallas(log_a, m, h0)
     return ref.rglru_ref(log_a, m, h0)

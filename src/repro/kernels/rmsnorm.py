@@ -16,6 +16,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.utils.backend import pallas_interpret
+
 
 def _rmsnorm_kernel(x_ref, scale_ref, o_ref, *, eps):
     x = x_ref[...].astype(jnp.float32)  # (bR, d)
@@ -24,8 +26,9 @@ def _rmsnorm_kernel(x_ref, scale_ref, o_ref, *, eps):
     o_ref[...] = y.astype(o_ref.dtype)
 
 
-def rmsnorm(x, scale, *, eps: float = 1e-6, block_rows: int = 128, interpret=True):
-    """x: (..., d); scale: (d,). Returns same shape/dtype as x."""
+def rmsnorm(x, scale, *, eps: float = 1e-6, block_rows: int = 128, interpret=None):
+    """x: (..., d); scale: (d,). Returns same shape/dtype as x.
+    ``interpret=None`` compiles on the TPU and interprets elsewhere."""
     orig_shape = x.shape
     d = orig_shape[-1]
     rows = 1
@@ -44,7 +47,7 @@ def rmsnorm(x, scale, *, eps: float = 1e-6, block_rows: int = 128, interpret=Tru
         ],
         out_specs=pl.BlockSpec((bR, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(x2, scale)
     return out.reshape(orig_shape)
 

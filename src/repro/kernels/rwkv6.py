@@ -20,9 +20,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.utils.backend import pallas_interpret
+
 
 def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sT_ref, s_scr,
                  *, L, N, n_chunks):
+    h = pl.program_id(1)
     c = pl.program_id(2)
 
     @pl.when(c == 0)
@@ -33,33 +36,50 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sT_ref, s_scr
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     w = w_ref[0, 0].astype(jnp.float32)  # log decay <= 0
-    u = u_ref[0].astype(jnp.float32)  # (N,)
+    u = u_ref[pl.ds(h, 1), :].astype(jnp.float32)  # (1, N): this head's bonus
     S = s_scr[...]  # (N, N) k-dim -> v-dim
 
-    ld = jnp.cumsum(w, axis=0)  # (L, N) inclusive
+    # inclusive cumulative log decay as a lower-triangular matmul (Mosaic
+    # has no cumsum); the 0/1 operand keeps it exact at full precision
+    tri_incl = (
+        jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
+        >= jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
+    ).astype(jnp.float32)
+    ld = jax.lax.dot_general(
+        tri_incl, w, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
+    )  # (L, N)
     ldm1 = ld - w  # exclusive cumulative log decay
     # pairwise decay exp(ld[t-1] - ld[s]) for s < t — exponent <= 0
     pair = ldm1[:, None, :] - ld[None, :, :]  # (Lt, Ls, N)
-    tri = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0) > jax.lax.broadcasted_iota(
-        jnp.int32, (L, L), 1
+    tri = jax.lax.broadcasted_iota(jnp.int32, (L, L, N), 0) > jax.lax.broadcasted_iota(
+        jnp.int32, (L, L, N), 1
     )
     # mask BEFORE exp: s >= t entries have positive exponents that would
     # overflow to inf (inf * 0 = nan)
-    A = jnp.exp(jnp.where(tri[:, :, None], pair, -jnp.inf))
+    A = jnp.exp(jnp.where(tri, pair, -jnp.inf))
     # W[t,s] = sum_n r[t,n] k[s,n] A[t,s,n]
     Wts = jnp.sum(r[:, None, :] * k[None, :, :] * A, axis=-1)  # (L, L)
     y = jax.lax.dot_general(Wts, v, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
     # diagonal bonus term u
-    du = jnp.sum(r * k * u[None, :], axis=-1)  # (L,)
+    du = jnp.sum(r * k * u, axis=-1)  # (L,)
     y = y + du[:, None] * v
     # cross-chunk: (r * exp(ldm1)) @ S
     y = y + jax.lax.dot_general(r * jnp.exp(ldm1), S, (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-    # state update: S' = diag(exp(ld_L)) S + (k * exp(ld_L - ld))^T @ v
-    decay_all = jnp.exp(ld[-1, :])  # (N,)
-    kscale = k * jnp.exp(ld[-1:, :] - ld)  # (L, N), exponent <= 0
-    S_new = S * decay_all[:, None] + jax.lax.dot_general(
+    # state update: S' = diag(exp(ld_L)) S + (k * exp(ld_L - ld))^T @ v,
+    # the diagonal scaling as a matmul so the decay row needs no transpose
+    ld_last = ld[L - 1 : L, :]  # (1, N)
+    eye = jax.lax.broadcasted_iota(jnp.int32, (N, N), 0) == jax.lax.broadcasted_iota(
+        jnp.int32, (N, N), 1
+    )
+    decay_diag = jnp.where(eye, jnp.exp(ld_last), 0.0)  # (N, N)
+    kscale = k * jnp.exp(ld_last - ld)  # (L, N), exponent <= 0
+    S_new = jax.lax.dot_general(
+        decay_diag, S, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
+    ) + jax.lax.dot_general(
         kscale, v, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
     s_scr[...] = S_new
@@ -70,8 +90,9 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sT_ref, s_scr
         sT_ref[0, 0] = S_new.astype(sT_ref.dtype)
 
 
-def wkv6(r, k, v, wlog, u, state, *, chunk=64, interpret=True):
+def wkv6(r, k, v, wlog, u, state, *, chunk=64, interpret=None):
     """r/k/v/wlog: (B, H, S, N); u: (H, N); state: (B, H, N, N) float32.
+    ``interpret=None`` compiles on the TPU and interprets elsewhere.
 
     Returns (y (B,H,S,N), final state (B,H,N,N))."""
     B, H, S, N = r.shape
@@ -88,7 +109,7 @@ def wkv6(r, k, v, wlog, u, state, *, chunk=64, interpret=True):
             pl.BlockSpec((1, 1, L, N), lambda b, h, c: (b, h, c, 0)),
             pl.BlockSpec((1, 1, L, N), lambda b, h, c: (b, h, c, 0)),
             pl.BlockSpec((1, 1, L, N), lambda b, h, c: (b, h, c, 0)),
-            pl.BlockSpec((1, N), lambda b, h, c: (h, 0)),
+            pl.BlockSpec((H, N), lambda b, h, c: (0, 0)),
             pl.BlockSpec((1, 1, N, N), lambda b, h, c: (b, h, 0, 0)),
         ],
         out_specs=[
@@ -100,6 +121,6 @@ def wkv6(r, k, v, wlog, u, state, *, chunk=64, interpret=True):
             jax.ShapeDtypeStruct(state.shape, jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((N, N), jnp.float32)],
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(r, k, v, wlog, u, state)
     return y, sT

@@ -21,6 +21,7 @@ import numpy as np
 from repro.configs import all_archs, get_arch
 from repro.models import build_model
 from repro.train.step import make_train_step
+from repro.utils.backend import use_compile_cache
 
 
 def main():
@@ -33,6 +34,7 @@ def main():
     ap.add_argument("--json", action="store_true", dest="as_json",
                     help="final line is one machine-readable JSON status object")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_arch(args.arch)
     if not args.full:

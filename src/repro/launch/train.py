@@ -29,6 +29,7 @@ from repro.core.trainer import FTTrainer
 from repro.data.synthetic import token_batches
 from repro.models import build_model
 from repro.train.step import make_train_step
+from repro.utils.backend import use_compile_cache
 from repro.utils.tree import tree_bytes
 
 
@@ -52,6 +53,7 @@ def main():
     ap.add_argument("--json", action="store_true", dest="as_json",
                     help="final line is one machine-readable JSON status object")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_arch(args.arch)
     if not args.full:

@@ -97,17 +97,6 @@ class Timed:
         }
 
 
-def _block(x):
-    """``jax.block_until_ready`` when jax is importable; pytrees pass
-    through, plain Python results are returned untouched."""
-    try:
-        import jax
-
-        return jax.block_until_ready(x)
-    except ImportError:  # pragma: no cover - jax is baked into the image
-        return x
-
-
 def timed(
     fn: Callable,
     *args,
@@ -120,16 +109,18 @@ def timed(
     """Measure ``fn(*args, **kwargs)``: ``warmup`` unrecorded calls (jit
     compilation, lru caches), then ``n`` timed calls, each synchronised
     via ``jax.block_until_ready`` on the result when ``block``."""
+    import jax
+
     out = Timed(name=name or getattr(fn, "__name__", "fn"), n=n, warmup=warmup)
     for _ in range(warmup):
         r = fn(*args, **kwargs)
         if block:
-            _block(r)
+            jax.block_until_ready(r)
     for _ in range(n):
         with stopwatch() as sw:
             r = fn(*args, **kwargs)
             if block:
-                r = _block(r)
+                r = jax.block_until_ready(r)
         out.times_s.append(sw.s)
         out.result = r
     return out
@@ -198,9 +189,9 @@ def profile_replay(
     the execute phase in ``jax.profiler.trace`` so the op-level timeline
     can be opened in TensorBoard/Perfetto."""
     import jax
-    from jax.experimental import enable_x64
 
     from repro.scenarios.trajectory import _quiet_donation, compile_batch, replay_program
+    from repro.utils.backend import x64
 
     with stopwatch() as sw_tape:
         batch = compile_batch(spec, n_seeds)
@@ -218,7 +209,7 @@ def profile_replay(
         donate=donate,
         record_slots=record_slots,
     )
-    with enable_x64(), _quiet_donation():
+    with x64(), _quiet_donation():
         with stopwatch() as sw_lower:
             lowered = fn.lower(*args)
         with stopwatch() as sw_compile:
@@ -271,10 +262,7 @@ def _decode_case(batch: int, seq_len: int, heads: int, head_dim: int, impl: str)
     kpos = jnp.tile(jnp.arange(seq_len, dtype=jnp.int32), (batch, 1))
     pos = seq_len - 1  # scalar decode position (the cache is full)
     if impl == "pallas":
-        import jax
-
-        interp = jax.default_backend() != "tpu"
-        return lambda: flash_decode(q, k, v, kpos, pos, block_k=128, interpret=interp)
+        return lambda: flash_decode(q, k, v, kpos, pos, block_k=128)
     return lambda: flash_decode_ref(q, k, v, kpos, pos)
 
 

@@ -27,14 +27,17 @@ import tempfile
 
 def _cmd_run(a) -> int:
     from repro.orchestrator.daemon import OrchestratorDaemon, SubprocessLauncher
-    from repro.orchestrator.plan import make_live_plan
+    from repro.orchestrator.plan import make_live_plan, plan_in_child
     from repro.orchestrator.spool import Spool
     from repro.scenarios import registry as scenario_registry
 
     spec = scenario_registry.get(a.scenario)
     if a.workload is not None:
         spec.workload = a.workload
-    plan = make_live_plan(
+    # planning runs JAX: do it in a child that has let go of the chip
+    # before any worker needs it
+    plan, device = plan_in_child(
+        make_live_plan,
         spec,
         time_scale=a.time_scale,
         seed=a.seed,
@@ -43,6 +46,7 @@ def _cmd_run(a) -> int:
         workload=spec.workload,
         n_seeds=a.plan_seeds,
     )
+    plan.device = device
     spool_dir = a.spool or tempfile.mkdtemp(prefix="repro_orchestrator_")
     spool = Spool(spool_dir)
     launcher = SubprocessLauncher(spool, spec.workload, plan.seed)
@@ -69,7 +73,8 @@ def _cmd_run(a) -> int:
             f"[orchestrator] {spec.name}: strategy={rep.final_strategy} "
             f"survived={rep.survived} live={rep.live_total_s and round(rep.live_total_s, 1)}s "
             f"predicted={round(rep.predicted_total_s, 1)}s "
-            f"migrations={rep.n_migrations} replans={rep.n_replans} spool={spool_dir}"
+            f"migrations={rep.n_migrations} replans={rep.n_replans} spool={spool_dir} "
+            f"planned_on={device['platform']}x{device['count']}"
         )
     return 0 if rep.survived else 1
 

@@ -53,7 +53,7 @@ from repro.core.failure import FailureEvent
 from repro.core.migration import DependencyGraph
 from repro.core.runtime import ClusterRuntime
 from repro.orchestrator import contract
-from repro.orchestrator.plan import DriftMonitor, LivePlan, scale_failure_rate
+from repro.orchestrator.plan import PROFILE, DriftMonitor, LivePlan, scale_failure_rate
 from repro.orchestrator.spool import Spool
 
 
@@ -212,7 +212,6 @@ class OrchestratorDaemon:
         launcher,
         *,
         injector="kill",
-        profile: str = "placentia",
         clock: Callable[[], float] = time.monotonic,
         async_sleep: Optional[Callable] = None,
         poll_wall_s: float = 0.05,
@@ -236,7 +235,6 @@ class OrchestratorDaemon:
         self.injector = (
             injector if not isinstance(injector, str) else injector_registry.get(injector)
         )
-        self.profile = profile
         self.clock = clock
         self.async_sleep = async_sleep if async_sleep is not None else asyncio.sleep
         self.poll_wall_s = float(poll_wall_s)
@@ -302,17 +300,41 @@ class OrchestratorDaemon:
         )
 
     # -------------------------------------------------------------- setup ---
+    def _workers_hold_chips(self) -> bool:
+        """Whether the live workers run JAX on an accelerator. Only the
+        planning child saw the devices (``plan.device``); CPU devices are
+        shared freely."""
+        from repro.orchestrator.worker import JAX_WORKLOADS
+
+        platform = self.plan.device.get("platform", "cpu")
+        return self.plan.workload in JAX_WORKLOADS and platform != "cpu"
+
+    def _check_chip_share(self) -> None:
+        """Refuse a launch that puts more JAX workers on the host than it
+        has chips: a chip belongs to one process, so the surplus workers
+        would fail or hang on its lock."""
+        if not self._workers_hold_chips():
+            return
+        device = self.plan.device
+        n_live = self.spec.n_nodes + self.spec.n_spares
+        if n_live > device["count"]:
+            raise RuntimeError(
+                f"workload {self.plan.workload!r} runs JAX in every worker: "
+                f"{self.spec.n_nodes} shard holders + {self.spec.n_spares} warm "
+                f"spares need {n_live} {device['platform']} chips, the host has "
+                f"{device['count']}; a chip belongs to one process"
+            )
+
     def _build_cluster(self):
         """Mirror ``CampaignEngine._build``: same runtime, same attach."""
         from repro.strategies import registry as strategy_registry
         from repro.telemetry import registry as detector_registry
-        from repro.workloads import resolve as resolve_workload
 
         spec = self.spec
         self.rt = ClusterRuntime(
             n_hosts=spec.n_nodes,
             n_spares=spec.n_spares,
-            profile=self.profile,
+            profile=PROFILE,
             graph=DependencyGraph.star(spec.n_nodes - 1)
             if spec.n_nodes > 1
             else DependencyGraph(),
@@ -320,13 +342,11 @@ class OrchestratorDaemon:
             racks=spec.effective_racks(),
         )
         self.strat = strategy_registry.get(self.plan.strategy, placement=spec.placement)
-        # same billing measure the engine uses for this workload, so the
-        # strategy's modelled reinstate/overhead (our scaled stalls) match
-        micro = resolve_workload(self.plan.workload, spec).micro(
-            self.profile, n_nodes=spec.n_nodes
-        )
+        # the plan carries the billing measure the engine uses for this
+        # workload, so the strategy's modelled reinstate/overhead (our
+        # scaled stalls) match
         payloads = {h: {"shard": h} for h in range(spec.n_nodes)}
-        self.strat.attach(self.rt, payloads, micro=micro, period_s=spec.period_s)
+        self.strat.attach(self.rt, payloads, micro=self.plan.micro, period_s=spec.period_s)
         self.shard_of_host = {h: h for h in range(spec.n_nodes)}
         self.detector = detector_registry.get(self.plan.detector)
         self.detector.bind(self.rt)
@@ -483,13 +503,18 @@ class OrchestratorDaemon:
         observed = self.spec
         if drift_info["cause"] == "failure_rate":
             observed = scale_failure_rate(self.spec, drift_info["ratio"])
+        device = None
         if self.planner is not None:
             new_name = self.planner(observed, self.plan, drift_info)
         else:
-            from repro.orchestrator.plan import choose_strategy
+            from repro.orchestrator.plan import choose_strategy, plan_in_child
 
-            new_name, _ = choose_strategy(
+            # the workers hold the chips mid-run: a child that asked for
+            # one would fail or hang, so it plans on the CPU
+            (new_name, _), device = plan_in_child(
+                choose_strategy,
                 observed,
+                jax_platforms="cpu" if self._workers_hold_chips() else None,
                 n_seeds=self.replan_seeds,
                 seed=self.plan.seed,
                 detector=self.plan.detector,
@@ -498,7 +523,8 @@ class OrchestratorDaemon:
         rep.n_replans += 1
         rep.replans.append(
             {"t_s": float(t_s), "cause": drift_info["cause"],
-             "ratio": drift_info["ratio"], "from": self.strat.name, "to": new_name}
+             "ratio": drift_info["ratio"], "from": self.strat.name, "to": new_name,
+             "device": device}
         )
         if rec is not None:
             rec.emit(
@@ -540,6 +566,7 @@ class OrchestratorDaemon:
             step_wall_s=plan.step_wall_s,
         )
 
+        self._check_chip_share()
         self._build_cluster()
         rec = None
         if self.trace_on:

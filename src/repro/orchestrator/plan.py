@@ -30,6 +30,11 @@ observed failure rate or measured step latency diverging beyond a
 ratio band — and tells the daemon to re-plan;
 :func:`scale_failure_rate` rewrites the spec to the observed intensity
 for the re-plan.
+
+The daemon asks none of this in its own process: a chip belongs to one
+process, and the daemon's workers need it. :func:`plan_in_child` runs a
+question in a fresh process that exits before the answer is returned, and
+reports which platform and how many chips that process saw.
 """
 from __future__ import annotations
 
@@ -44,6 +49,40 @@ DEFAULT_CANDIDATES = ("central_single", "agent", "core", "hybrid")
 
 #: steps per checkpoint period when the caller doesn't set a resolution
 DEFAULT_STEPS_PER_PERIOD = 2
+#: the cost profile live campaigns are priced and run under: the plan's
+#: billing measure and the daemon's runtime both use it (the engine's and
+#: the Monte-Carlo's default)
+PROFILE = "placentia"
+
+
+# ----------------------------------------------------------- isolation ---
+def _answer_in_child(fn, args, kwargs, jax_platforms):
+    import jax
+
+    from repro.utils.backend import use_compile_cache
+
+    if jax_platforms:
+        jax.config.update("jax_platforms", jax_platforms)
+    use_compile_cache()
+    out = fn(*args, **kwargs)
+    devices = jax.devices()
+    return out, {"platform": devices[0].platform, "count": len(devices)}
+
+
+def plan_in_child(fn, /, *args, jax_platforms: Optional[str] = None, **kwargs):
+    """``fn(*args, **kwargs)`` answered in a fresh spawned process.
+
+    Returns ``(answer, device)``, ``device`` being ``{"platform", "count"}``
+    as JAX reported them in the child. ``jax_platforms`` (e.g. ``"cpu"``)
+    restricts the child's JAX to those platforms, for when the chips are
+    already held. The child has exited, and so let go of the chip, before
+    this returns; the calling process initialises no JAX backend."""
+    import multiprocessing
+    from concurrent.futures import ProcessPoolExecutor
+
+    ctx = multiprocessing.get_context("spawn")
+    with ProcessPoolExecutor(max_workers=1, mp_context=ctx) as pool:
+        return pool.submit(_answer_in_child, fn, args, kwargs, jax_platforms).result()
 
 
 # -------------------------------------------------------------- strategy ---
@@ -152,8 +191,15 @@ class LivePlan:
     step_wall_s: float  # paced wall duration of one step (probe folded in)
     ckpt_every_steps: int
     predicted_total_s: float  # engine bill for this exact (spec, seed)
+    # the workload's billing measure (MicroCosts) under PROFILE.
+    # Calibrating it may run the job's own JAX step, so the planning
+    # process takes it
+    micro: object = None
     scores: Dict[str, Dict] = field(default_factory=dict)  # per-candidate MC
     calibration: Dict = field(default_factory=dict)  # measure_step_wall_s output
+    # {"platform", "count"} the planning process saw (plan_in_child); empty
+    # when the plan was made in-process
+    device: Dict = field(default_factory=dict)
 
     def to_dict(self) -> Dict:
         return {
@@ -171,6 +217,7 @@ class LivePlan:
             "calibration": {
                 k: v for k, v in self.calibration.items() if k != "surface"
             },
+            "device": self.device,
         }
 
 
@@ -223,6 +270,9 @@ def make_live_plan(
     predicted = predicted_makespan_s(
         spec, strategy, seed=seed, detector=detector, workload=workload
     )
+    from repro.workloads import resolve as resolve_workload
+
+    micro = resolve_workload(workload, spec).micro(PROFILE, n_nodes=spec.n_nodes)
     return LivePlan(
         spec=spec,
         strategy=strategy,
@@ -235,6 +285,7 @@ def make_live_plan(
         step_wall_s=float(step_wall_s),
         ckpt_every_steps=int(steps_per_period),
         predicted_total_s=float(predicted),
+        micro=micro,
         scores=scores,
         calibration=calibration,
     )

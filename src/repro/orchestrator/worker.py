@@ -83,20 +83,13 @@ class AnalyticProgram(StepProgram):
 
 
 class GenomeProgram(StepProgram):
-    """One search sub-job of the paper's genome job; a step is one chunk."""
+    """One search sub-job of the paper's genome job; a step is one chunk.
 
-    def __init__(self, seed: int, n_shards: int, n_steps: int, shard: int):
-        from repro.data.genome import GenomeSearchJob, make_genome
+    ``job`` is the shared :class:`~repro.data.genome.GenomeSearchJob`; the
+    sub-job's cursor and partial hits are the state that migrates."""
 
-        # every worker rebuilds the same job deterministically from the seed,
-        # so a migrated shard resumes on identical data
-        total_chunks = n_shards * n_steps
-        genome, patterns, _ = make_genome(
-            length=2048 * total_chunks, n_patterns=6, seed=seed
-        )
-        self.job = GenomeSearchJob(
-            genome, patterns, n_search=n_shards, chunks_per_node=n_steps
-        )
+    def __init__(self, job, shard: int):
+        self.job = job
         self.state = {"node": shard, "cursor": 0, "hits": []}
 
     def step(self) -> None:
@@ -166,18 +159,47 @@ class TrainProgram(StepProgram):
         return {"loss": loss, "steps_done": self.steps_done}
 
 
+def _genome_program(seed: int, n_shards: int, n_steps: int, shard: int) -> GenomeProgram:
+    from repro.data.genome import GenomeSearchJob, make_genome
+
+    # every worker rebuilds the same job deterministically from the seed,
+    # so a migrated shard resumes on identical data
+    genome, patterns, _ = make_genome(
+        length=2048 * n_shards * n_steps, n_patterns=6, seed=seed
+    )
+    job = GenomeSearchJob(genome, patterns, n_search=n_shards, chunks_per_node=n_steps)
+    return GenomeProgram(job, shard)
+
+
+def _train_program(seed: int, n_shards: int, n_steps: int, shard: int) -> TrainProgram:
+    return TrainProgram(seed, shard)
+
+
+def _analytic_program(seed: int, n_shards: int, n_steps: int, shard: int) -> AnalyticProgram:
+    return AnalyticProgram(seed, shard)
+
+
+#: workload name -> (shard-program factory, whether its step runs JAX and
+#: so needs a device of its own in every live worker)
+_PROGRAMS = {
+    "analytic": (_analytic_program, False),
+    "genome_search": (_genome_program, True),
+    "train_llm": (_train_program, True),
+    "train": (_train_program, True),
+}
+
+JAX_WORKLOADS = frozenset(name for name, (_, uses_jax) in _PROGRAMS.items() if uses_jax)
+
+
 def make_program(workload: str, seed: int, n_shards: int, n_steps: int, shard: int) -> StepProgram:
     """Bind a workload name to a runnable shard program."""
-    if workload == "analytic":
-        return AnalyticProgram(seed, shard)
-    if workload == "genome_search":
-        return GenomeProgram(seed, n_shards, n_steps, shard)
-    if workload in ("train_llm", "train"):
-        return TrainProgram(seed, shard)
-    raise KeyError(
-        f"no step program bound for workload {workload!r}; "
-        "have ['analytic', 'genome_search', 'train_llm']"
-    )
+    if workload not in _PROGRAMS:
+        raise KeyError(
+            f"no step program bound for workload {workload!r}; "
+            "have ['analytic', 'genome_search', 'train_llm']"
+        )
+    factory, _ = _PROGRAMS[workload]
+    return factory(seed, n_shards, n_steps, shard)
 
 
 # -------------------------------------------------------------- worker loop ---
@@ -351,6 +373,10 @@ def main(argv=None) -> int:
         help="self-watchdog: exit with the stalled code after this many wall seconds",
     )
     a = p.parse_args(argv)
+    if a.workload in JAX_WORKLOADS:
+        from repro.utils.backend import use_compile_cache
+
+        use_compile_cache()
     w = Worker(
         Spool(a.spool), a.worker_id, a.workload, a.seed, abort_after_s=a.abort_after_s
     )

@@ -39,7 +39,7 @@ This module splits scenario execution into two layers:
 consumes the same compiled tape, driving the real Agent/VirtualCore/
 HybridUnit machinery), and the differential tests assert the kernel
 matches it trial-for-trial on identical seeds. The kernel runs under
-``jax.experimental.enable_x64`` so its arithmetic is the engine's float64
+:func:`repro.utils.backend.x64` so its arithmetic is the engine's float64
 arithmetic, not an approximation of it.
 
 **Fleet-scale execution shape.** The kernel is built to hold its
@@ -76,6 +76,7 @@ from repro.core.rules import SD_THRESHOLD_BYTES, Z_THRESHOLD
 from repro.scenarios.spec import ScenarioSpec
 from repro.strategies import registry as strategy_registry
 from repro.strategies.base import CostContext, FaultToleranceStrategy, StrategyCostTable
+from repro.utils.backend import x64
 from repro.utils.tree import tree_bytes
 
 __all__ = [
@@ -382,6 +383,11 @@ class _TableStatic:
 #: to finite keys equals the pairwise earlier-or-tied-lower-index count).
 _PAIRWISE_RANK_MAX_HOSTS = 128
 
+#: spare-pool sequence number of a host that is not in the pool. The pool
+#: is int32, so the placement argmin reduces in s32: over a float64 pool
+#: the TPU compiler narrows that argmin's value accumulators to bf16
+_NOT_POOLED = np.iinfo(np.int32).max
+
 #: the replay program's runtime ``coeffs`` argument (float64 [8])
 _COEFF_FIELDS = (
     "probe_s_per_hour",
@@ -418,7 +424,7 @@ def _compiled_replayer(static: _ReplayStatic, tstatic: _TableStatic):
     arrive as the runtime ``coeffs`` vector, so swapping strategies or
     workloads that share structure reuses the compiled program. Must be
     called — and the result invoked — under
-    ``jax.experimental.enable_x64`` so times and cost accumulators trace
+    :func:`repro.utils.backend.x64` so times and cost accumulators trace
     as float64 (the engine's arithmetic).
 
     The program's signature is ``fn(coeffs, tape)``: ``coeffs`` the
@@ -438,6 +444,7 @@ def _compiled_replayer(static: _ReplayStatic, tstatic: _TableStatic):
     max_strikes = static.max_strikes
     mode = tstatic.mode
     idxH = jnp.arange(H, dtype=jnp.int32)
+    idxS = jnp.arange(n_slots, dtype=jnp.int64)
 
     # initial dependency degrees of the engine's star topology (genome
     # search: workers feed one combiner, spares carry no edges)
@@ -462,15 +469,14 @@ def _compiled_replayer(static: _ReplayStatic, tstatic: _TableStatic):
             black=jnp.zeros(H, bool),
             strikes=jnp.zeros(H, jnp.int32),
             occupied=idxH < static.n_workers,
-            # spare-pool FIFO: entry-sequence number per host (inf = not
-            # in the pool); argmin over eligible entries reproduces the
-            # engine's list order through removals and repair re-appends
+            # spare-pool FIFO: entry-sequence number per host
+            # (_NOT_POOLED = not in the pool); argmin over eligible entries
+            # reproduces the engine's list order through removals and
+            # repair re-appends
             spare_seq=jnp.where(
-                idxH >= static.n_workers,
-                (idxH - static.n_workers).astype(jnp.float64),
-                jnp.inf,
-            ),
-            next_seq=jnp.asarray(float(static.n_spares), dtype=jnp.float64),
+                idxH >= static.n_workers, idxH - static.n_workers, _NOT_POOLED
+            ).astype(jnp.int32),
+            next_seq=jnp.asarray(static.n_spares, dtype=jnp.int32),
             deg=jnp.asarray(deg0, dtype=jnp.int32),
             attempt=jnp.zeros(H, dtype=jnp.float64),
             rcount=jnp.asarray(0, jnp.int32),
@@ -521,10 +527,8 @@ def _compiled_replayer(static: _ReplayStatic, tstatic: _TableStatic):
                 order = jnp.argsort(ra, stable=True)
                 rank = jnp.zeros(H, dtype=jnp.int32).at[order].set(idxH)
             nrep = jnp.sum(due)
-            spare_seq = jnp.where(
-                due, c["next_seq"] + rank.astype(jnp.float64), c["spare_seq"]
-            )
-            next_seq = c["next_seq"] + nrep.astype(jnp.float64)
+            spare_seq = jnp.where(due, c["next_seq"] + rank.astype(jnp.int32), c["spare_seq"])
+            next_seq = c["next_seq"] + nrep.astype(jnp.int32)
             down = c["down"] & ~due
             repair_at = jnp.where(due, jnp.inf, c["repair_at"])
             n_reprovisioned = c["n_reprovisioned"] + nrep.astype(jnp.int32)
@@ -539,8 +543,12 @@ def _compiled_replayer(static: _ReplayStatic, tstatic: _TableStatic):
             v = jnp.clip(victim, 0, H - 1)
             n_events = c["n_events"] + active.astype(jnp.int32)
             processed = active & ~down[v]  # down victims coalesce
+            # per-host writes are selects against one-hot rows, never
+            # scatters: on a TPU v5e a vmapped scatter into a bool carry
+            # drops some seeds' writes (int32 and float scatters do not)
+            at_v = idxH == v
 
-            strikes = c["strikes"].at[v].add(processed.astype(jnp.int32))
+            strikes = c["strikes"] + (at_v & processed).astype(jnp.int32)
             if static.repair_none:
                 permanent = processed
             else:
@@ -556,8 +564,8 @@ def _compiled_replayer(static: _ReplayStatic, tstatic: _TableStatic):
                 comp = x["comp"]
                 allowed = jnp.where(pa, comp == comp[v], True)
                 okf = okf & allowed
-            pool = jnp.isfinite(spare_seq) & okf
-            i1 = jnp.argmin(jnp.where(pool, spare_seq, jnp.inf)).astype(jnp.int32)
+            pool = (spare_seq != _NOT_POOLED) & okf
+            i1 = jnp.argmin(jnp.where(pool, spare_seq, _NOT_POOLED)).astype(jnp.int32)
             nb1 = (v - 1) % H
             nb2 = (v + 1) % H
             m3 = okf & (idxH != v)
@@ -628,31 +636,32 @@ def _compiled_replayer(static: _ReplayStatic, tstatic: _TableStatic):
 
             # -- migrate the sub-job (occupancy, pool, dependency degree,
             #    cold attempt clock follow the work)
-            occupied = c["occupied"].at[v].set(jnp.where(handled, False, c["occupied"][v]))
-            occupied = occupied.at[tgt].set(jnp.where(handled, True, occupied[tgt]))
-            spare_seq = spare_seq.at[tgt].set(jnp.where(handled, jnp.inf, spare_seq[tgt]))
-            degv = c["deg"][v]
-            deg = c["deg"].at[tgt].set(jnp.where(handled, degv, c["deg"][tgt]))
-            deg = deg.at[v].set(jnp.where(handled, 0, deg[v]))
+            moved_from = at_v & handled
+            moved_to = (idxH == tgt) & handled
+            occupied = (c["occupied"] & ~moved_from) | moved_to
+            spare_seq = jnp.where(moved_to, _NOT_POOLED, spare_seq)
+            deg = jnp.where(moved_to, c["deg"][v], c["deg"])
+            deg = jnp.where(moved_from, 0, deg)
             attempt = c["attempt"]
             if mode == "cold":
-                attempt = attempt.at[tgt].set(jnp.where(handled, t, attempt[tgt]))
+                attempt = jnp.where(moved_to, t, attempt)
 
             # -- fail the victim; blacklist or schedule its repair
-            down = down.at[v].set(jnp.where(processed, True, down[v]))
-            spare_seq = spare_seq.at[v].set(jnp.where(processed, jnp.inf, spare_seq[v]))
+            down = down | (at_v & processed)
+            spare_seq = jnp.where(at_v & processed, _NOT_POOLED, spare_seq)
             newly_black = permanent & ~stranded
-            black = c["black"].at[v].set(c["black"][v] | newly_black)
+            black = c["black"] | (at_v & newly_black)
             n_blacklisted = c["n_blacklisted"] + newly_black.astype(jnp.int32)
             sched = processed & ~stranded & ~permanent
             rdraw = draws[jnp.clip(c["rcount"], 0, n_slots - 1)]
-            repair_at = repair_at.at[v].set(jnp.where(sched, t + rdraw, repair_at[v]))
+            repair_at = jnp.where(at_v & sched, t + rdraw, repair_at)
             rcount = c["rcount"] + sched.astype(jnp.int32)
 
             alive = c["alive"] & ~stranded
             failed_at = jnp.where(stranded, t, c["failed_at"])
-            fired = c["fired"].at[j].set(handled)
-            tgt_rec = c["tgt_rec"].at[j].set(jnp.where(handled, tgt, -1).astype(jnp.int32))
+            at_j = idxS == j
+            fired = jnp.where(at_j, handled, c["fired"])
+            tgt_rec = jnp.where(at_j, jnp.where(handled, tgt, -1), c["tgt_rec"])
 
             # per-slot decision record for trace reconstruction: exactly
             # the facts the engine's emit sites see (resolved victim,
@@ -756,17 +765,19 @@ def _compiled_replayer(static: _ReplayStatic, tstatic: _TableStatic):
 
     vmapped = jax.vmap(one_seed, in_axes=(None, 0))
     if static.n_devices > 1:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import Mesh, PartitionSpec
 
         mesh = Mesh(
             np.asarray(jax.devices()[: static.n_devices]), axis_names=("seeds",)
         )
-        vmapped = shard_map(
+        # seeds never communicate: no collective to check, and the scan's
+        # replicated initial carry need not be cast to the varying axis
+        vmapped = jax.shard_map(
             vmapped,
             mesh=mesh,
             in_specs=(PartitionSpec(), PartitionSpec("seeds")),
             out_specs=PartitionSpec("seeds"),
+            check_vma=False,
         )
     # donate the tape: slot-shaped outputs alias the input buffers and
     # consumed tape buffers free mid-execution instead of staying live
@@ -841,9 +852,7 @@ def _resolve_program(
     ``args = (coeffs, tape)`` and ``ctx`` the resolved billing inputs
     (strategy cost table, ``rules_agent_small``) the SLO biller shares
     with the engine; ``fn(*args)`` — and any ``fn.lower(*args)`` —
-    must run under ``enable_x64``."""
-    from jax.experimental import enable_x64
-
+    must run under :func:`~repro.utils.backend.x64`."""
     from repro.telemetry import registry as detector_registry
     from repro.telemetry.detector import Detector
     from repro.workloads import resolve as resolve_workload
@@ -949,7 +958,7 @@ def _resolve_program(
         mechanism=table.mechanism,
         ckpt_invalidation=bool(table.ckpt_invalidation),
     )
-    with enable_x64():  # program construction traces x64 constants
+    with x64():  # program construction traces x64 constants
         fn = _compiled_replayer(static, tstatic)
     args = (_table_coeffs(table), tape)
     ctx = {"table": table, "rules_agent_small": static.rules_agent_small}
@@ -979,7 +988,7 @@ def replay_program(
     ``fn.lower(*args).compile()`` splits compile from execute time —
     what :func:`repro.obs.profile.profile_replay` measures. Everything
     (lower, compile, invoke) must run under
-    ``jax.experimental.enable_x64``, the kernel's required precision."""
+    :func:`repro.utils.backend.x64`, the kernel's required precision."""
     fn, args, _, _, _ = _resolve_program(
         spec,
         batch,
@@ -1053,7 +1062,6 @@ def replay_batch(
     :func:`default_seed_devices`). Both are pure execution-shape knobs:
     results are bit-identical across every tile size and device count."""
     import jax
-    from jax.experimental import enable_x64
 
     from repro.scenarios.spec import degrade_slowdown_s
 
@@ -1071,7 +1079,7 @@ def replay_batch(
         tile_slots=tile_slots,
         n_devices=n_devices,
     )
-    with enable_x64(), _quiet_donation():
+    with x64(), _quiet_donation():
         out = fn(*args)
         out = jax.block_until_ready(out)
     out = {k: np.asarray(v) for k, v in out.items()}

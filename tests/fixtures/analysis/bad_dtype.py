@@ -1,10 +1,11 @@
 """Fixture: dtype-less constructor in a module that manages the
-``enable_x64`` context — the f32 default would shear off the engine's
+x64 context — the f32 default would shear off the engine's
 f64 the moment the array is built outside the context."""
 import jax.numpy as jnp
-from jax.experimental import enable_x64
+
+from repro.utils.backend import x64
 
 
 def build_state(n_hosts):
-    with enable_x64():
+    with x64():
         return jnp.zeros(n_hosts)  # dtype-x64 violation

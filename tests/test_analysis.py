@@ -221,6 +221,21 @@ def test_run_analysis_returns_sorted_findings():
     assert [f.line for f in findings] == sorted(f.line for f in findings)
 
 
+@pytest.mark.parametrize("rel, mode", [
+    ("scenarios/trajectory.py", "x64"),
+    ("obs/profile.py", "x64"),
+    ("kernels/decode_attention.py", "kernel"),
+    ("utils/backend.py", None),
+])
+def test_dtype_scope_follows_the_x64_helper(rel, mode):
+    """Importing ``repro.utils.backend.x64`` is what makes a module an x64
+    module; the helper's own home is not one."""
+    from repro.analysis.rules.dtypes import _mode
+
+    project = Project.load([REPO / "src" / "repro" / rel], exclude=())
+    assert _mode(project.modules[0]) == mode
+
+
 def test_syntax_error_files_are_skipped(tmp_path, capsys):
     (tmp_path / "broken.py").write_text("def oops(:\n")
     (tmp_path / "fine.py").write_text("x = 1\n")

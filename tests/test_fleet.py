@@ -121,6 +121,43 @@ def test_replay_bit_identical_across_device_counts(
     assert_bit_identical(ref, got, f"n_devices={n_dev}")
 
 
+_SHARDED_VS_ONE = """
+import numpy as np
+from repro.scenarios import registry
+from repro.scenarios.trajectory import compile_batch, default_seed_devices, replay_batch
+
+spec = registry.get("fleet_stress")
+batch = compile_batch(spec, 8)
+assert default_seed_devices(8) == 4
+ref = replay_batch(spec, batch, "core", n_devices=1)
+got = replay_batch(spec, batch, "core")
+bad = [k for k in ref if not np.array_equal(ref[k], got[k], equal_nan=True)]
+assert not bad, bad
+print("bit-identical", sorted(ref))
+"""
+
+
+def test_sharded_replay_bit_identical_in_a_four_device_process(tmp_path):
+    """The default planning call on a multi-device host shards the seed
+    axis with ``jax.shard_map``: run it in a process with four forced CPU
+    devices (a fresh process, since the device count is fixed at start-up)
+    and require the single-device replay bit for bit."""
+    import os
+    import subprocess
+    import sys
+
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    env["PYTHONPATH"] = os.pathsep.join(filter(None, [src, env.get("PYTHONPATH")]))
+    proc = subprocess.run(
+        [sys.executable, "-c", _SHARDED_VS_ONE], env=env, cwd=tmp_path,
+        capture_output=True, text=True, timeout=600,
+    )
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert "bit-identical" in proc.stdout
+
+
 def test_default_seed_devices_divides_seeds():
     """The helper picks the largest local-device count dividing n_seeds
     (shard_map needs an even split), never exceeding what's attached."""
@@ -169,10 +206,9 @@ def test_donation_drops_peak_memory(fleet_spec, fleet_micro):
     """Donated tape buffers alias into the record-mode [seeds, slots]
     outputs, so the compiled program's peak memory drops vs donate=False
     (visible as alias_size_in_bytes > 0 in XLA's memory analysis)."""
-    from jax.experimental import enable_x64
-
     from repro.obs.profile import _memory_analysis
     from repro.scenarios.trajectory import _quiet_donation
+    from repro.utils.backend import x64
 
     batch = compile_batch(fleet_spec, 8)
     peaks = {}
@@ -186,7 +222,7 @@ def test_donation_drops_peak_memory(fleet_spec, fleet_micro):
             donate=donate,
             n_devices=1,  # isolate donation from shard_map's buffer layout
         )
-        with enable_x64(), _quiet_donation():
+        with x64(), _quiet_donation():
             mem = _memory_analysis(fn.lower(*args).compile())
         if mem is None:
             pytest.skip("backend exposes no memory_analysis")

@@ -420,6 +420,122 @@ def test_live_trace_exports_like_a_simulated_one(tmp_path):
     assert "failure" in names and "migrate" in names
 
 
+# ------------------------------------------------ one process per chip ---
+def test_jax_workload_refused_when_workers_outnumber_chips(tmp_path):
+    """4 shard holders + 2 warm spares of a JAX workload on a one-chip
+    host: the daemon refuses before launching anything, naming the cause,
+    instead of letting five workers hang on the chip's lock."""
+    spawned = []
+    plan = stub_plan(live_spec("genome_search"))
+    plan.device = {"platform": "tpu", "count": 1}
+    with pytest.raises(RuntimeError, match="need 6 tpu chips, the host has 1"):
+        run_stub_daemon(plan, tmp_path / "jax", launcher_hook=spawned.append)
+    assert spawned[0].n_spawn_attempts == 0
+
+    # the analytic workload runs no JAX in its workers: unchanged
+    plan = stub_plan(live_spec("analytic"))
+    plan.device = {"platform": "tpu", "count": 1}
+    rep, _, _ = run_stub_daemon(plan, tmp_path / "analytic")
+    assert rep.survived and sorted(rep.results) == [0, 1, 2, 3]
+
+
+def test_replan_plans_on_the_cpu_while_workers_hold_every_chip(tmp_path, monkeypatch):
+    """6 JAX workers on a 6-chip host hold every chip mid-run: the re-plan
+    child is told to plan on the CPU instead of asking for a held chip,
+    and the replan entry records the device the child reports."""
+    import repro.orchestrator.plan as plan_mod
+
+    asked = []
+
+    def fake_plan_in_child(fn, /, *args, jax_platforms=None, **kwargs):
+        asked.append(jax_platforms)
+        return ("hybrid", {}), {"platform": jax_platforms or "tpu", "count": 1}
+
+    monkeypatch.setattr(plan_mod, "plan_in_child", fake_plan_in_child)
+    plan = stub_plan(live_spec("genome_search"))
+    plan.device = {"platform": "tpu", "count": 6}
+    kills = []
+
+    def hook(launcher):
+        kills.extend(
+            (t, lambda h=h: launcher.stubs[h].deliver("kill")) for t, h in ((1.0, 1), (1.3, 2))
+        )
+
+    rep, _, _ = run_stub_daemon(
+        plan, tmp_path, injector="none", script=kills, launcher_hook=hook,
+        max_replans=1,
+    )
+    assert asked == ["cpu"]
+    assert rep.n_replans == 1 and rep.replans[0]["to"] == "hybrid"
+    assert rep.replans[0]["device"] == {"platform": "cpu", "count": 1}
+
+    # the analytic workload's workers hold no chip: the child may take one
+    asked.clear()
+    plan = stub_plan(live_spec("analytic"))
+    plan.device = {"platform": "tpu", "count": 1}
+    kills.clear()
+    rep, _, _ = run_stub_daemon(
+        plan, tmp_path / "analytic", injector="none", script=kills,
+        launcher_hook=hook, max_replans=1,
+    )
+    assert asked == [None] and rep.replans[0]["device"]["platform"] == "tpu"
+
+
+_PLAN_AND_REPLAN = """
+import json, sys
+from jax._src import xla_bridge
+from repro.orchestrator.daemon import OrchestratorDaemon
+from repro.orchestrator.plan import make_live_plan, plan_in_child
+from repro.orchestrator.spool import Spool
+from repro.orchestrator.testing import FakeClock, StubLauncher, scripted_sleeper
+from repro.scenarios import registry
+
+spec = registry.get("live_genome_single")
+plan, device = plan_in_child(
+    make_live_plan, spec, time_scale=900.0, seed=0, strategy=None,
+    candidates=("central_single", "core"), n_seeds=8, calibrate=False,
+)
+plan.device = device
+clock = FakeClock()
+launcher = StubLauncher(Spool(sys.argv[1]), clock)
+# two organic kills the spec never declared: the drift monitor re-plans
+kills = [(t, lambda h=h: launcher.stubs[h].deliver("kill")) for t, h in ((1.0, 1), (1.3, 2))]
+daemon = OrchestratorDaemon(
+    plan, launcher.spool, launcher, injector="none", clock=clock,
+    async_sleep=scripted_sleeper(clock, launcher, script=kills),
+    poll_wall_s=0.05, deadline_wall_s=600.0, max_replans=1, replan_seeds=8,
+)
+rep = daemon.run_sync()
+print(json.dumps({"device": device, "n_replans": rep.n_replans,
+                  "replan_device": rep.replans[0]["device"],
+                  "survived": rep.survived,
+                  "backend_in_daemon": xla_bridge.backends_are_initialized()}))
+"""
+
+
+def test_planning_and_replan_never_start_jax_in_the_daemon(tmp_path):
+    """The oracle's plan and a forced re-plan both run in a child process
+    that exits before the answer returns: the daemon's own process never
+    initialises a JAX backend, so it never holds a chip its workers need."""
+    import subprocess
+    import sys
+
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(filter(None, [src, env.get("PYTHONPATH")]))
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "jax_cache")
+    proc = subprocess.run(
+        [sys.executable, "-c", _PLAN_AND_REPLAN, str(tmp_path / "spool")],
+        env=env, capture_output=True, text=True, timeout=600,
+    )
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert out["n_replans"] == 1 and out["survived"]
+    assert out["device"]["count"] >= 1 and out["device"]["platform"]
+    assert out["replan_device"]["platform"] == out["device"]["platform"]
+    assert out["backend_in_daemon"] is False
+
+
 # --------------------------------------------------- real subprocesses ---
 def test_subprocess_analytic_campaign_end_to_end(tmp_path):
     """4 real worker processes, injector kills one, daemon completes the
