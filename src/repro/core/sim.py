@@ -47,6 +47,7 @@ from repro.core.failure import PREDICTION_LEAD_S, mean_random_failure_time
 from repro.core.migration import DependencyGraph
 from repro.core.runtime import ClusterRuntime
 from repro.core.virtual_core import VirtualCore
+from repro.obs.profile import span
 from repro.strategies.base import CostContext, StrategyRow
 from repro.strategies.registry import (
     get as get_strategy,
@@ -128,66 +129,71 @@ def _measure_micro_cached(
     s_p_bytes: int,
     payload_elems: int,
 ) -> MicroCosts:
-    profile = get_profile(profile_name)
+    # a memo hit never reaches here, so it records no span
+    with span("repro.micro"):
+        profile = get_profile(profile_name)
 
-    def mk_rt():
-        rt = ClusterRuntime(
-            n_hosts=n_nodes, n_spares=2, profile=profile, graph=DependencyGraph.star(n_nodes - 1)
+        def mk_rt():
+            rt = ClusterRuntime(
+                n_hosts=n_nodes,
+                n_spares=2,
+                profile=profile,
+                graph=DependencyGraph.star(n_nodes - 1),
+            )
+            # ensure requested dependency count on node 0
+            rt.graph.in_edges.setdefault(0, [])
+            while rt.graph.degree(0) < z:
+                peer = (rt.graph.degree(0) % (n_nodes - 1)) + 1
+                rt.graph.in_edges[0].append(peer)
+                rt.graph.out_edges.setdefault(peer, []).append(0)
+            return rt
+
+        payload = {"partial": np.zeros(payload_elems, np.float32), "cursor": 123}
+
+        rt = mk_rt()
+        rt.occupy(0, payload, "agent:0")
+        ag = Agent(0, 0, payload)
+        arep = ag.migrate(rt)
+        assert arep["hash_ok"]
+
+        rt = mk_rt()
+        rt.occupy(0, payload, "core:0")
+        vc = VirtualCore(0, 0)
+        crep = vc.migrate_job(rt)
+        assert crep["hash_ok"]
+
+        # reinstate: control plane only — but scale the modelled metadata term to
+        # the *experiment's* S_d/S_p (the in-process payload is a small stand-in)
+        from repro.core.migration import META_LOG_COEF
+
+        speed = max(profile.node_speed, 0.1)
+        meta_measured = META_LOG_COEF * np.log2(max(arep["bytes"], 2)) / speed
+        meta_target = META_LOG_COEF * np.log2(max(s_p_bytes, 2)) / speed
+        agent_reinstate = arep["reinstate_s"] - meta_measured + meta_target
+        core_reinstate = crep["reinstate_s"] - meta_measured + meta_target
+
+        staging = s_d_bytes / profile.node_bw
+        agent_overhead = LOG_MINING_S["agent"] / speed + staging + profile.proc_spawn_s
+        core_overhead = LOG_MINING_S["core"] / speed + staging + profile.proc_spawn_s
+
+        total_bytes = s_d_bytes * max(n_nodes - 1, 1)
+        co, cr = {}, {}
+        for kind in CHECKPOINT_KINDS:  # infra variants, not strategy dispatch
+            cfgk = CheckpointPolicyCfg(kind=kind, n_servers=3)
+            co[kind] = modelled_checkpoint_overhead_s(cfgk, profile, total_bytes, n_nodes)
+            cr[kind] = modelled_restore_s(cfgk, profile, total_bytes, n_nodes)
+
+        return MicroCosts(
+            predict_s=PREDICTION_LEAD_S,
+            agent_reinstate_s=float(agent_reinstate),
+            core_reinstate_s=float(core_reinstate),
+            agent_overhead_s=float(agent_overhead),
+            core_overhead_s=float(core_overhead),
+            ckpt_overhead_s=co,
+            ckpt_reinstate_s=cr,
+            measured_agent_s=float(arep["reinstate_measured_s"]),
+            measured_core_s=float(crep["reinstate_measured_s"]),
         )
-        # ensure requested dependency count on node 0
-        rt.graph.in_edges.setdefault(0, [])
-        while rt.graph.degree(0) < z:
-            peer = (rt.graph.degree(0) % (n_nodes - 1)) + 1
-            rt.graph.in_edges[0].append(peer)
-            rt.graph.out_edges.setdefault(peer, []).append(0)
-        return rt
-
-    payload = {"partial": np.zeros(payload_elems, np.float32), "cursor": 123}
-
-    rt = mk_rt()
-    rt.occupy(0, payload, "agent:0")
-    ag = Agent(0, 0, payload)
-    arep = ag.migrate(rt)
-    assert arep["hash_ok"]
-
-    rt = mk_rt()
-    rt.occupy(0, payload, "core:0")
-    vc = VirtualCore(0, 0)
-    crep = vc.migrate_job(rt)
-    assert crep["hash_ok"]
-
-    # reinstate: control plane only — but scale the modelled metadata term to
-    # the *experiment's* S_d/S_p (the in-process payload is a small stand-in)
-    from repro.core.migration import META_LOG_COEF
-
-    speed = max(profile.node_speed, 0.1)
-    meta_measured = META_LOG_COEF * np.log2(max(arep["bytes"], 2)) / speed
-    meta_target = META_LOG_COEF * np.log2(max(s_p_bytes, 2)) / speed
-    agent_reinstate = arep["reinstate_s"] - meta_measured + meta_target
-    core_reinstate = crep["reinstate_s"] - meta_measured + meta_target
-
-    staging = s_d_bytes / profile.node_bw
-    agent_overhead = LOG_MINING_S["agent"] / speed + staging + profile.proc_spawn_s
-    core_overhead = LOG_MINING_S["core"] / speed + staging + profile.proc_spawn_s
-
-    total_bytes = s_d_bytes * max(n_nodes - 1, 1)
-    co, cr = {}, {}
-    for kind in CHECKPOINT_KINDS:  # infra variants, not strategy dispatch
-        cfgk = CheckpointPolicyCfg(kind=kind, n_servers=3)
-        co[kind] = modelled_checkpoint_overhead_s(cfgk, profile, total_bytes, n_nodes)
-        cr[kind] = modelled_restore_s(cfgk, profile, total_bytes, n_nodes)
-
-    return MicroCosts(
-        predict_s=PREDICTION_LEAD_S,
-        agent_reinstate_s=float(agent_reinstate),
-        core_reinstate_s=float(core_reinstate),
-        agent_overhead_s=float(agent_overhead),
-        core_overhead_s=float(core_overhead),
-        ckpt_overhead_s=co,
-        ckpt_reinstate_s=cr,
-        measured_agent_s=float(arep["reinstate_measured_s"]),
-        measured_core_s=float(crep["reinstate_measured_s"]),
-    )
 
 
 # tests that want a fresh wall-clock measurement can drop the memo table

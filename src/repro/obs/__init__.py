@@ -4,8 +4,9 @@ and profiling hooks.
 The subsystem is strictly opt-in and zero-overhead when unused: the
 engine's recorder is ``None`` unless ``trace=True``, the replay kernel
 only returns per-slot arrays under ``record_slots=True`` (a separate
-cached jit program), and the profiling hooks are plain functions that
-cost nothing until called.
+cached jit program), the profiling hooks are plain functions that
+cost nothing until called, and a ``span`` records nothing without a
+``jax.profiler`` session.
 
 Layout — submodules import lazily so ``repro.obs.profile`` (pure
 stdlib) never drags jax in:
@@ -21,6 +22,7 @@ stdlib) never drags jax in:
     Chrome-trace / Perfetto JSON serialisation
 ``obs.profile``
     the repo's one wall-clock timing idiom (``timed``/``stopwatch``),
+    ``span``, the program's host spans on the ``jax.profiler`` clock,
     compile-vs-execute splits + seeds/sec for the vmapped replay kernel,
     measured Pallas step surfaces per shard count
 """
@@ -31,6 +33,7 @@ from repro.obs.profile import (  # noqa: F401  (dependency-free, eager)
     kernel_step_surface,
     now_s,
     profile_replay,
+    span,
     stopwatch,
     time_pallas_kernel,
     timed,
@@ -57,6 +60,7 @@ __all__ = [
     "timed",
     "stopwatch",
     "now_s",
+    "span",
     "profile_replay",
     "time_pallas_kernel",
     "kernel_step_surface",

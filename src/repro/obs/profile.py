@@ -1,4 +1,4 @@
-"""Profiling hooks: one wall-clock timing idiom for the whole repo.
+"""Profiling hooks: the repo's one wall-clock timing idiom and its spans.
 
 Every measured number the repo reports — speedup certs, calibrated
 workload surfaces, bench section timings — used to be an ad-hoc
@@ -7,9 +7,12 @@ and ``block_until_ready`` handling. This module is the single home for
 that idiom:
 
 :func:`stopwatch` / :func:`now_s`
-    the primitive perf-counter pair as a context manager
-    (``utils.timing`` re-exports these, so existing callers keep
-    working);
+    the primitive perf-counter pair as a context manager;
+:func:`span`
+    a named host span on the profiler's clock, for the program's host
+    layers (``repro.micro``, ``repro.tapes``, ``repro.verdicts``,
+    ``repro.frames``): recorded beside the device's ops in the same
+    ``jax.profiler`` trace, inert without one;
 :func:`timed`
     measure a callable properly: warmup iterations first (jit compiles,
     caches fill), ``jax.block_until_ready`` on the result of every timed
@@ -26,11 +29,6 @@ that idiom:
     surfaces in ``workloads/builtin.py`` (interpret mode on CPU,
     compiled on TPU; the backend is recorded next to every number so a
     CPU-interpret figure is never mistaken for a TPU one).
-
-Pass ``trace_dir=`` to :func:`profile_replay` to additionally capture a
-``jax.profiler`` trace of the execute phase (viewable in
-TensorBoard/Perfetto); the hook is inert by default so profiling stays
-zero-overhead when unused.
 """
 from __future__ import annotations
 
@@ -63,6 +61,21 @@ def stopwatch():
         yield sw
     finally:
         sw.s = time.perf_counter() - t0
+
+
+def span(name: str, **meta):
+    """``with span("repro.tapes", strategy="agent"): ...`` — a host span.
+
+    A thin ``jax.profiler.TraceAnnotation``: while a profiler session
+    runs, the span lands in its ``.xplane.pb`` on the host plane, on the
+    same clock as the device's ops, with ``meta`` as the event's stats;
+    the profiler keeps spans in memory and writes them when the trace
+    stops. Without a session it records nothing (about a microsecond a
+    span). The program's spans are leaves: none encloses another, a
+    jitted call, or a per-seed loop's body."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(name, **meta)
 
 
 @dataclass
@@ -165,7 +178,6 @@ def profile_replay(
     detector="oracle",
     workload=None,
     n_exec: int = 3,
-    trace_dir: Optional[str] = None,
     tile_slots: int = 8,
     n_devices: Optional[int] = None,
     donate: bool = True,
@@ -185,9 +197,7 @@ def profile_replay(
     ``tile_slots`` / ``n_devices`` profile the tile/shard execution shape
     (results are bit-identical across both; only the cost moves), and
     ``memory`` carries the compiled program's argument/output/temp
-    byte split so donation savings are observable. ``trace_dir`` wraps
-    the execute phase in ``jax.profiler.trace`` so the op-level timeline
-    can be opened in TensorBoard/Perfetto."""
+    byte split so donation savings are observable."""
     import jax
 
     from repro.scenarios.trajectory import _quiet_donation, compile_batch, replay_program
@@ -216,13 +226,7 @@ def profile_replay(
             compiled = lowered.compile()
         memory = _memory_analysis(compiled)
         compiled(*args)  # warm-up: first dispatch pays transfers
-        if trace_dir is not None:
-            jax.profiler.start_trace(trace_dir)
-        try:
-            t_exec = timed(compiled, *args, n=n_exec, warmup=0, name="replay_exec")
-        finally:
-            if trace_dir is not None:
-                jax.profiler.stop_trace()
+        t_exec = timed(compiled, *args, n=n_exec, warmup=0, name="replay_exec")
     exec_s = t_exec.mean_s
     return {
         "family": spec.name,
@@ -240,7 +244,6 @@ def profile_replay(
         "seeds_per_s": round(n_seeds / max(exec_s, 1e-9), 1),
         "compile_over_execute": round((sw_lower.s + sw_compile.s) / max(exec_s, 1e-9), 1),
         "memory": memory,
-        "trace_dir": trace_dir,
     }
 
 

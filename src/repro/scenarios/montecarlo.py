@@ -43,6 +43,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.profile import span
+
 
 @dataclass(frozen=True)
 class MCParams:
@@ -295,44 +297,46 @@ def mc_trajectories(
         tile_slots=tile_slots,
         n_devices=n_devices,
     )
-    frames = frames_from_replay(
-        spec,
-        out,
-        getattr(strategy, "name", strategy),
-        detector=detector.name if isinstance(detector, Detector) else detector,
-        workload=workload.name,
-        base_seed=seed,
-    )
-    totals = out["total_s"]
-    ok = out["survived"]
-    alive = totals[ok]
-    stat = lambda f, d=np.nan: float(f(alive)) if alive.size else d
-    slo = aggregate_slo(out)
-    return {
-        **({"slo": slo} if slo is not None else {}),
-        "scenario": spec.name,
-        "strategy": strategy,
-        # the cost model the trials were billed under (advisory when an
-        # explicit micro overrode it)
-        "workload": workload.name,
-        "n_seeds": int(batch.n_seeds),
-        "survival_rate": float(np.mean(ok)),
-        "mean_s": stat(np.mean),
-        "std_s": stat(np.std),
-        "p5_s": stat(lambda x: np.percentile(x, 5)),
-        "p50_s": stat(lambda x: np.percentile(x, 50)),
-        "p95_s": stat(lambda x: np.percentile(x, 95)),
-        "mean_failed_at_s": float(np.mean(out["failed_at_s"][~ok])) if (~ok).any() else None,
-        "counters": {
-            k: float(np.mean(out[k]))
-            for k in (
-                "n_events",
-                "n_handled",
-                "n_migrations",
-                "n_blacklisted",
-                "n_reprovisioned",
-            )
-        },
-        "frames": aggregate_frames(frames),
-        "trials": out,
-    }
+    name = getattr(strategy, "name", strategy)
+    with span("repro.frames", strategy=name):
+        frames = frames_from_replay(
+            spec,
+            out,
+            name,
+            detector=detector.name if isinstance(detector, Detector) else detector,
+            workload=workload.name,
+            base_seed=seed,
+        )
+        totals = out["total_s"]
+        ok = out["survived"]
+        alive = totals[ok]
+        stat = lambda f, d=np.nan: float(f(alive)) if alive.size else d
+        slo = aggregate_slo(out)
+        return {
+            **({"slo": slo} if slo is not None else {}),
+            "scenario": spec.name,
+            "strategy": strategy,
+            # the cost model the trials were billed under (advisory when an
+            # explicit micro overrode it)
+            "workload": workload.name,
+            "n_seeds": int(batch.n_seeds),
+            "survival_rate": float(np.mean(ok)),
+            "mean_s": stat(np.mean),
+            "std_s": stat(np.std),
+            "p5_s": stat(lambda x: np.percentile(x, 5)),
+            "p50_s": stat(lambda x: np.percentile(x, 50)),
+            "p95_s": stat(lambda x: np.percentile(x, 95)),
+            "mean_failed_at_s": float(np.mean(out["failed_at_s"][~ok])) if (~ok).any() else None,
+            "counters": {
+                k: float(np.mean(out[k]))
+                for k in (
+                    "n_events",
+                    "n_handled",
+                    "n_migrations",
+                    "n_blacklisted",
+                    "n_reprovisioned",
+                )
+            },
+            "frames": aggregate_frames(frames),
+            "trials": out,
+        }

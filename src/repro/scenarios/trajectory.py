@@ -73,6 +73,7 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from repro.core.rules import SD_THRESHOLD_BYTES, Z_THRESHOLD
+from repro.obs.profile import span
 from repro.scenarios.spec import ScenarioSpec
 from repro.strategies import registry as strategy_registry
 from repro.strategies.base import CostContext, FaultToleranceStrategy, StrategyCostTable
@@ -271,53 +272,54 @@ def compile_batch(
     pad/stack them (padding slots: ``t = +inf``, ``valid = False``). The
     slot count is rounded up to a multiple of 8 so the jitted replay
     program is shared across batches whose max event count jitters."""
-    tapes = [compile_tape(spec, base_seed + s) for s in range(n_seeds)]
-    H = spec.n_nodes + spec.n_spares
-    n = max(1, max(t.n_slots for t in tapes))
-    n = -(-n // 8) * 8
-    S = n_seeds
+    with span("repro.tapes"):
+        tapes = [compile_tape(spec, base_seed + s) for s in range(n_seeds)]
+        H = spec.n_nodes + spec.n_spares
+        n = max(1, max(t.n_slots for t in tapes))
+        n = -(-n // 8) * 8
+        S = n_seeds
 
-    times = np.full((S, n), np.inf, np.float64)
-    victim = np.full((S, n), -1, np.int32)
-    parent = np.full((S, n), -1, np.int32)
-    pred = np.zeros((S, n), bool)
-    during = np.zeros((S, n), bool)
-    valid = np.zeros((S, n), bool)
-    draws = np.zeros((S, n), np.float64)
-    rcorr = np.zeros((S, n), bool)
-    p_act = np.zeros((S, n), bool)
-    # all tapes share the spec's (deterministic) partition timeline, so
-    # their part_comp widths agree: H with cuts, 1 (compact) without
-    W = max(tp.part_comp.shape[1] for tp in tapes)
-    p_comp = np.full((S, n, W), -1, np.int32)
-    for s, tp in enumerate(tapes):
-        k = tp.n_slots
-        times[s, :k] = tp.times
-        victim[s, :k] = tp.victim
-        parent[s, :k] = tp.parent
-        pred[s, :k] = tp.predictable
-        during[s, :k] = tp.during_ckpt
-        valid[s, :k] = True
-        draws[s, :k] = tp.repair_draws
-        rcorr[s, :k] = tp.rack_corr
-        p_act[s, :k] = tp.part_active
-        p_comp[s, :k] = tp.part_comp
+        times = np.full((S, n), np.inf, np.float64)
+        victim = np.full((S, n), -1, np.int32)
+        parent = np.full((S, n), -1, np.int32)
+        pred = np.zeros((S, n), bool)
+        during = np.zeros((S, n), bool)
+        valid = np.zeros((S, n), bool)
+        draws = np.zeros((S, n), np.float64)
+        rcorr = np.zeros((S, n), bool)
+        p_act = np.zeros((S, n), bool)
+        # all tapes share the spec's (deterministic) partition timeline, so
+        # their part_comp widths agree: H with cuts, 1 (compact) without
+        W = max(tp.part_comp.shape[1] for tp in tapes)
+        p_comp = np.full((S, n, W), -1, np.int32)
+        for s, tp in enumerate(tapes):
+            k = tp.n_slots
+            times[s, :k] = tp.times
+            victim[s, :k] = tp.victim
+            parent[s, :k] = tp.parent
+            pred[s, :k] = tp.predictable
+            during[s, :k] = tp.during_ckpt
+            valid[s, :k] = True
+            draws[s, :k] = tp.repair_draws
+            rcorr[s, :k] = tp.rack_corr
+            p_act[s, :k] = tp.part_active
+            p_comp[s, :k] = tp.part_comp
 
-    return TapeBatch(
-        spec_name=spec.name,
-        seeds=np.arange(base_seed, base_seed + n_seeds, dtype=np.int64),
-        n_hosts=H,
-        times=times,
-        victim=victim,
-        parent=parent,
-        predictable=pred,
-        during_ckpt=during,
-        valid=valid,
-        repair_draws=draws,
-        rack_corr=rcorr,
-        part_active=p_act,
-        part_comp=p_comp,
-    )
+        return TapeBatch(
+            spec_name=spec.name,
+            seeds=np.arange(base_seed, base_seed + n_seeds, dtype=np.int64),
+            n_hosts=H,
+            times=times,
+            victim=victim,
+            parent=parent,
+            predictable=pred,
+            during_ckpt=during,
+            valid=valid,
+            repair_draws=draws,
+            rack_corr=rcorr,
+            part_active=p_act,
+            part_comp=p_comp,
+        )
 
 
 # ======================================================================
@@ -868,15 +870,16 @@ def _resolve_program(
 
     # per-seed verdict tapes (the oracle's is the predictable bits verbatim)
     verdicts = np.zeros_like(batch.predictable)
-    for s in range(batch.n_seeds):
-        v, _ = det.verdict_tape(
-            spec,
-            times=batch.times[s],
-            predictable=batch.predictable[s],
-            rack_corr=batch.rack_corr[s],
-            seed=int(batch.seeds[s]),
-        )
-        verdicts[s] = v
+    with span("repro.verdicts", strategy=strat.name):
+        for s in range(batch.n_seeds):
+            v, _ = det.verdict_tape(
+                spec,
+                times=batch.times[s],
+                predictable=batch.predictable[s],
+                rack_corr=batch.rack_corr[s],
+                seed=int(batch.seeds[s]),
+            )
+            verdicts[s] = v
 
     placement = placement or spec.placement or "nearest-spare"
     if placement not in ("nearest-spare", "partition-aware"):

@@ -1,2 +1,1 @@
 from repro.utils.tree import tree_bytes, tree_hash, tree_equal, split_params
-from repro.utils.timing import Timer, now_s

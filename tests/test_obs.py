@@ -30,7 +30,7 @@ from repro.obs.metrics import (
     frames_from_replay,
     verdict_ledger,
 )
-from repro.obs.profile import Timed, stopwatch, timed
+from repro.obs.profile import Timed, span, stopwatch, timed
 from repro.obs.trace import TraceEvent, reconstruct_traces, schedule_events
 from repro.scenarios import mc_trajectories, registry
 from repro.scenarios.engine import CampaignEngine
@@ -297,15 +297,74 @@ def test_timed_and_stopwatch():
     assert sw.s >= 0.0
 
 
-def test_utils_timing_compat():
-    """utils.timing stays a working alias of the obs idiom."""
-    from repro.utils import timing
-
-    assert timing.stopwatch is stopwatch
-    t = timing.Timer()
-    with t.section("a"):
+def test_span_is_inert_without_a_profiler_session():
+    """A span outside a profiler session records nothing and passes
+    exceptions through."""
+    with span("repro.probe", strategy="agent"):
         pass
-    assert t.times["a"][0] >= 0.0 and t.total("a") == sum(t.times["a"])
+    with pytest.raises(KeyError):
+        with span("repro.probe"):
+            raise KeyError("through")
+
+
+# one request of the chip benchmark's traffic at a tiny size, traced in a
+# fresh process as the benchmark's planning child traces it: cold caches,
+# so every program is traced, lowered and compiled inside the call
+_TRACED_CALL = """
+import json, sys
+from chipbench import child
+from repro.scenarios import registry
+request = {"candidates": ("central_single", "agent"), "n_seeds": 8, "seed": 0,
+           "detector": "ewma_straggler", "workload": "analytic"}
+out = child.decide("repro.orchestrator.plan:choose_strategy", registry.get("table2_random"),
+                   request, sys.argv[1])
+print(json.dumps({"call_s": out["call_s"]}))
+"""
+_SPAN_READERS = ("micro_calibration_s", "tape_compile_s", "verdict_tapes_s", "frames_s",
+                 "jit_trace_lower_s", "xla_compile_s", "untraced_call_share")
+
+
+def test_traced_oracle_call_has_leaf_spans(tmp_path, monkeypatch):
+    """The oracle's host layers land as leaf spans in the profiler's trace:
+    each once per call or per candidate (none in a per-seed loop), none
+    enclosing another or a jitted call, and every span reader finds them."""
+    import math
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parents[1]
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join([str(root), str(root / "src")]))
+    proc = subprocess.run([sys.executable, "-c", _TRACED_CALL, str(tmp_path)], cwd=root,
+                          env=env, capture_output=True, text=True, timeout=240)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    call_s = json.loads(proc.stdout.strip().splitlines()[-1])["call_s"]
+
+    monkeypatch.syspath_prepend(str(root))
+    from chipbench import tracefile
+    from chipbench.run import load_reader
+
+    trace = tracefile.load(str(tmp_path))
+    host = trace["host"]
+    counts = {}
+    for name, _, _ in host:
+        if name.startswith("repro."):
+            counts[name] = counts.get(name, 0) + 1
+    assert counts == {"repro.micro": 1, "repro.tapes": 1, "repro.verdicts": 2, "repro.frames": 2}
+    leaves = [h for h in host if h[0].startswith("repro.")]
+    inner = leaves + [h for h in host if h[0].startswith("PjitFunction(")]
+    assert any(h[0] == "PjitFunction(one_seed)" for h in inner)
+    for name, start, dur in leaves:
+        for other, a, d in inner:
+            if (other, a, d) != (name, start, dur):
+                assert not (start <= a and a + d <= start + dur), (name, other)
+    run = {"trace": trace, "call_s": [call_s]}
+    for reader in _SPAN_READERS:
+        value = load_reader(reader)(run)
+        assert value is not None and math.isfinite(value), reader
+    assert load_reader("xla_compile_s")(run) > 0
+    assert 0 <= load_reader("untraced_call_share")(run) < 0.25
 
 
 def test_measured_step_surface_mapping():

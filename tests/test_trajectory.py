@@ -13,7 +13,14 @@ from repro.scenarios import mc_totals, mc_trajectories, registry
 from repro.scenarios.engine import CampaignEngine
 from repro.scenarios.montecarlo import params_from_scenario
 from repro.scenarios.spec import FailureProcessSpec, ScenarioSpec
-from repro.scenarios.trajectory import compile_batch, compile_tape, replay_batch
+from repro.scenarios.trajectory import (
+    _quiet_donation,
+    compile_batch,
+    compile_tape,
+    replay_batch,
+    replay_program,
+)
+from repro.utils.backend import x64
 
 
 _MICRO = {}
@@ -320,3 +327,14 @@ def test_default_cost_table_for_custom_strategy(micro):
     t = Custom().cost_table(CostContext(micro=micro, period_h=1.0))
     assert t.mode == "window" and not t.ckpt_invalidation
     assert t.reinstate_s == 11.0 and t.overhead_s == 7.0
+
+
+def test_replay_program_module_is_named_jit_one_seed(micro):
+    """The chip benchmark finds the replay program's device runs by its
+    module's name (``chipbench/metrics/replay_device_ms.py``), so a rename
+    fails here instead of leaving that metric silently empty."""
+    spec = registry.get("table2_random")
+    fn, args = replay_program(spec, compile_batch(spec, 8), "central_single", micro=micro)
+    with x64(), _quiet_donation():
+        text = fn.lower(*args).as_text()
+    assert text.split(None, 2)[:2] == ["module", "@jit_one_seed"]
