@@ -706,9 +706,9 @@ def run_profiling(micro, n_seeds: int, dry_run: bool) -> dict:
         )
     out["fleet_memory"] = mem_ab
     # how many distinct XLA programs the whole bench compiled so far vs
-    # how many replays were served from cache (cost-table coefficients
-    # travel as traced values, so strategies sharing a structural shape
-    # share one compile)
+    # how many replays were served from cache (the cost table travels as
+    # traced values, so every strategy on one scenario shape shares one
+    # compile)
     out["program_cache"] = replay_cache_stats()
 
     # interpret-mode Pallas is slow: tiny shapes in dry-run, modest in full

@@ -11,7 +11,8 @@ that idiom:
 :func:`span`
     a named host span on the profiler's clock, for the program's host
     layers (``repro.micro``, ``repro.tapes``, ``repro.verdicts``,
-    ``repro.frames``): recorded beside the device's ops in the same
+    ``repro.frames``) and for each replay program built
+    (``repro.replay_program``): recorded beside the device's ops in the same
     ``jax.profiler`` trace, inert without one;
 :func:`timed`
     measure a callable properly: warmup iterations first (jit compiles,

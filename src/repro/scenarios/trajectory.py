@@ -54,11 +54,11 @@ tape stays O(events + nodes), not O(nodes × horizon)), the slot axis is
 inner per-slot scan, bit-identical across tile sizes because padding
 slots are provable no-ops — and the seed axis is **sharded** across
 devices with ``shard_map`` (``n_devices=``; force a multi-device CPU
-with ``XLA_FLAGS=--xla_force_host_platform_device_count=8``). Cost-table
-*values* travel as a traced ``float64[8]`` coefficient vector rather
-than baked-in constants, so one compiled program serves every strategy
-that shares a structural :class:`_TableStatic` shape —
-:func:`replay_cache_stats` reports the resulting hit rate. Tape buffers
+with ``XLA_FLAGS=--xla_force_host_platform_device_count=8``). The cost
+table — its numbers and the flags that pick its billing branch — travels
+as a traced ``float64[11]`` coefficient vector rather than baked-in
+constants, so one compiled program serves every strategy on one scenario
+shape — :func:`replay_cache_stats` reports the resulting hit rate. Tape buffers
 are donated to the jit program (``donate_argnums``) so fleet-size
 record-mode replays reuse their input storage.
 """
@@ -362,19 +362,6 @@ class _ReplayStatic:
     donate: bool = True
 
 
-@dataclass(frozen=True)
-class _TableStatic:
-    """The branch-selecting flags of a :class:`StrategyCostTable`. Only
-    these reach the tracer as Python values — the numeric coefficients
-    travel as a runtime jnp vector (``_COEFF_FIELDS`` order), so one
-    compiled program serves every cost table sharing this structure
-    (e.g. all four workloads' pricings of one strategy)."""
-
-    mode: str  # "window" | "proactive" | "cold"
-    mechanism: str  # "agent" | "core" | "rules"
-    ckpt_invalidation: bool
-
-
 #: StrategyCostTable numeric fields, in the order they are packed into
 #: host-axis width at or below which repair-completion ranking uses the
 #: vectorised O(H^2) pairwise comparison matrix instead of a stable
@@ -390,7 +377,9 @@ _PAIRWISE_RANK_MAX_HOSTS = 128
 #: the TPU compiler narrows that argmin's value accumulators to bf16
 _NOT_POOLED = np.iinfo(np.int32).max
 
-#: the replay program's runtime ``coeffs`` argument (float64 [8])
+#: the replay program's runtime ``coeffs`` argument (float64 [11]): these
+#: StrategyCostTable numbers, then its mode's and mechanism's positions in
+#: ``_MODES`` / ``_MECHANISMS`` and its ckpt_invalidation as 0 or 1
 _COEFF_FIELDS = (
     "probe_s_per_hour",
     "predict_s",
@@ -401,16 +390,29 @@ _COEFF_FIELDS = (
     "core_reinstate_s",
     "core_overhead_s",
 )
+_MODES = ("window", "proactive", "cold")
+_MECHANISMS = ("agent", "core", "rules")
 
 
 def _table_coeffs(table: StrategyCostTable) -> np.ndarray:
-    return np.asarray([getattr(table, f) for f in _COEFF_FIELDS], np.float64)
+    if table.mode not in _MODES or table.mechanism not in _MECHANISMS:
+        raise ValueError(
+            f"cost table mode {table.mode!r} / mechanism {table.mechanism!r}; "
+            f"the replay kernel bills modes {_MODES} and mechanisms {_MECHANISMS}"
+        )
+    flags = [
+        _MODES.index(table.mode),
+        _MECHANISMS.index(table.mechanism),
+        float(table.ckpt_invalidation),
+    ]
+    return np.asarray([getattr(table, f) for f in _COEFF_FIELDS] + flags, np.float64)
 
 
 def replay_cache_stats() -> Dict[str, int]:
     """Compile-cache counters for the replay program. A sweep over N
-    cost tables sharing one (scenario shape, table structure) should
-    show N-1 hits, not N compiles — the bench report records these."""
+    cost tables on one scenario shape — any strategies, any workloads'
+    pricings — should show N-1 hits, not N compiles; the bench report
+    records these."""
     info = _compiled_replayer.cache_info()
     return {
         "hits": int(info.hits),
@@ -420,20 +422,22 @@ def replay_cache_stats() -> Dict[str, int]:
 
 
 @lru_cache(maxsize=128)
-def _compiled_replayer(static: _ReplayStatic, tstatic: _TableStatic):
+def _compiled_replayer(static: _ReplayStatic):
     """Build (and cache) the jitted, vmapped replay program for one
-    (scenario-shape, cost-table-structure) pair. Cost-table *values*
-    arrive as the runtime ``coeffs`` vector, so swapping strategies or
-    workloads that share structure reuses the compiled program. Must be
-    called — and the result invoked — under
+    scenario shape. The whole cost table arrives as the runtime
+    ``coeffs`` vector — its numbers and the flags that pick the billing
+    branch — so every strategy and workload replayed on one shape reuses
+    the compiled program: the step computes each loss clock and selects
+    the table's. Must be called — and the result invoked — under
     :func:`repro.utils.backend.x64` so times and cost accumulators trace
-    as float64 (the engine's arithmetic).
+    as float64 (the engine's arithmetic). A cache miss records one
+    ``repro.replay_program`` span; a hit records none.
 
     The program's signature is ``fn(coeffs, tape)``: ``coeffs`` the
-    float64 [8] ``_COEFF_FIELDS`` vector, ``tape`` a dict of ``[S, ...]``
-    slot arrays. The tape argument's device buffers are donated
-    (``donate_argnums=(1,)``) so the scan working set aliases them
-    instead of holding inputs and carries live simultaneously."""
+    float64 [11] vector of :func:`_table_coeffs`, ``tape`` a dict of
+    ``[S, ...]`` slot arrays. The tape argument's device buffers are
+    donated (``donate_argnums=(1,)``) so the scan working set aliases
+    them instead of holding inputs and carries live simultaneously."""
     import jax
     import jax.numpy as jnp
 
@@ -444,7 +448,6 @@ def _compiled_replayer(static: _ReplayStatic, tstatic: _TableStatic):
     period_s = static.period_s
     horizon_s = static.horizon_s
     max_strikes = static.max_strikes
-    mode = tstatic.mode
     idxH = jnp.arange(H, dtype=jnp.int32)
     idxS = jnp.arange(n_slots, dtype=jnp.int64)
 
@@ -465,6 +468,13 @@ def _compiled_replayer(static: _ReplayStatic, tstatic: _TableStatic):
         c_agent_ovh = coeffs[5]
         c_core_rst = coeffs[6]
         c_core_ovh = coeffs[7]
+        # the table's billing branch, as runtime flags
+        window = coeffs[8] == _MODES.index("window")
+        proactive = coeffs[8] == _MODES.index("proactive")
+        cold = coeffs[8] == _MODES.index("cold")
+        agent = coeffs[9] == _MECHANISMS.index("agent")
+        rules = coeffs[9] == _MECHANISMS.index("rules")
+        ckpt_invalidation = coeffs[10] != 0.0
         init = dict(
             down=jnp.zeros(H, bool),
             repair_at=jnp.full(H, jnp.inf, dtype=jnp.float64),
@@ -594,46 +604,44 @@ def _compiled_replayer(static: _ReplayStatic, tstatic: _TableStatic):
             handled = processed & has_work & (target >= 0)
             tgt = jnp.clip(target, 0, H - 1)
 
-            # -- per-event billing from the StrategyCostTable
+            # -- per-event billing from the StrategyCostTable: every
+            #    branch is computed as the engine bills it, and the table's
+            #    flags select one (a select, never arithmetic, so each
+            #    table's figures are those of a program built for it alone)
             wstart = jnp.floor(t / period_s) * period_s
-            if mode == "window":
-                if tstatic.ckpt_invalidation:
-                    # mid-checkpoint failure: restore from one window back
-                    # plus the wasted partial write
-                    lost_ev = (t - wstart) + jnp.where(dur, period_s, 0.0)
-                    ovh_ev = c_overhead * jnp.where(dur, 1.5, 1.0)
-                else:
-                    lost_ev = t - wstart
-                    ovh_ev = c_overhead
-                rst_ev = c_reinstate
-            elif mode == "proactive":
-                if tstatic.mechanism == "agent":
-                    is_agent = jnp.asarray(True, dtype=jnp.bool_)
-                elif tstatic.mechanism == "core":
-                    is_agent = jnp.asarray(False, dtype=jnp.bool_)
-                else:  # "rules": Z-negotiation per event (Rules 1-3)
-                    if static.rules_agent_small:
-                        is_agent = c["deg"][v] > Z_THRESHOLD
-                    else:
-                        is_agent = jnp.asarray(False, dtype=jnp.bool_)
-                rst_m = jnp.where(is_agent, c_agent_rst, c_core_rst)
-                ovh_ev = jnp.where(is_agent, c_agent_ovh, c_core_ovh)
-                # a failure is only *saved* when the detector claimed it AND
-                # a real lead window existed (ground-truth signature); every
-                # claim — true or false — pays the prediction work
-                lost_ev = jnp.where(vrd & prd, 0.0, t - wstart)
-                rst_ev = rst_m + jnp.where(vrd, c_predict, 0.0)
-            else:  # "cold": lose everything since the sub-job's last start
-                lost_ev = t - c["attempt"][v]
-                rst_ev = c_reinstate
-                ovh_ev = jnp.asarray(0.0, dtype=jnp.float64)
+            # "window": a mid-checkpoint failure under checkpoint
+            # invalidation restores from one window back plus the wasted
+            # partial write
+            lost_window = jnp.where(
+                ckpt_invalidation, (t - wstart) + jnp.where(dur, period_s, 0.0), t - wstart
+            )
+            ovh_window = jnp.where(
+                ckpt_invalidation, c_overhead * jnp.where(dur, 1.5, 1.0), c_overhead
+            )
+            # "proactive": agent or core, or for "rules" the Z-negotiation
+            # per event (Rules 1-3)
+            is_agent = agent
+            if static.rules_agent_small:
+                is_agent = is_agent | (rules & (c["deg"][v] > Z_THRESHOLD))
+            rst_m = jnp.where(is_agent, c_agent_rst, c_core_rst)
+            ovh_proactive = jnp.where(is_agent, c_agent_ovh, c_core_ovh)
+            # a failure is only *saved* when the detector claimed it AND
+            # a real lead window existed (ground-truth signature); every
+            # claim — true or false — pays the prediction work
+            lost_proactive = jnp.where(vrd & prd, 0.0, t - wstart)
+            rst_proactive = rst_m + jnp.where(vrd, c_predict, 0.0)
+            # "cold": lose everything since the sub-job's last start
+            lost_cold = t - c["attempt"][v]
+            lost_ev = jnp.where(window, lost_window, jnp.where(proactive, lost_proactive, lost_cold))
+            rst_ev = jnp.where(proactive, rst_proactive, c_reinstate)
+            ovh_ev = jnp.where(window, ovh_window, jnp.where(proactive, ovh_proactive, 0.0))
 
             lost = c["lost"] + jnp.where(handled, lost_ev, 0.0)
             reinstate = c["reinstate"] + jnp.where(handled, rst_ev, 0.0)
             overhead = c["overhead"] + jnp.where(handled, ovh_ev, 0.0)
             n_handled = c["n_handled"] + handled.astype(jnp.int32)
-            n_migrations = c["n_migrations"] + (
-                handled.astype(jnp.int32) if mode == "proactive" else 0
+            n_migrations = c["n_migrations"] + jnp.where(
+                proactive, handled.astype(jnp.int32), 0
             )
 
             # -- migrate the sub-job (occupancy, pool, dependency degree,
@@ -644,9 +652,7 @@ def _compiled_replayer(static: _ReplayStatic, tstatic: _TableStatic):
             spare_seq = jnp.where(moved_to, _NOT_POOLED, spare_seq)
             deg = jnp.where(moved_to, c["deg"][v], c["deg"])
             deg = jnp.where(moved_from, 0, deg)
-            attempt = c["attempt"]
-            if mode == "cold":
-                attempt = jnp.where(moved_to, t, attempt)
+            attempt = jnp.where(cold & moved_to, t, c["attempt"])
 
             # -- fail the victim; blacklist or schedule its repair
             down = down | (at_v & processed)
@@ -765,26 +771,29 @@ def _compiled_replayer(static: _ReplayStatic, tstatic: _TableStatic):
                 out["slot_" + k] = v.reshape((n_slots,) + v.shape[2:])
         return out
 
-    vmapped = jax.vmap(one_seed, in_axes=(None, 0))
-    if static.n_devices > 1:
-        from jax.sharding import Mesh, PartitionSpec
+    # wrapping the function traces nothing: the span marks the cache miss
+    # and encloses no JAX work
+    with span("repro.replay_program"):
+        vmapped = jax.vmap(one_seed, in_axes=(None, 0))
+        if static.n_devices > 1:
+            from jax.sharding import Mesh, PartitionSpec
 
-        mesh = Mesh(
-            np.asarray(jax.devices()[: static.n_devices]), axis_names=("seeds",)
-        )
-        # seeds never communicate: no collective to check, and the scan's
-        # replicated initial carry need not be cast to the varying axis
-        vmapped = jax.shard_map(
-            vmapped,
-            mesh=mesh,
-            in_specs=(PartitionSpec(), PartitionSpec("seeds")),
-            out_specs=PartitionSpec("seeds"),
-            check_vma=False,
-        )
-    # donate the tape: slot-shaped outputs alias the input buffers and
-    # consumed tape buffers free mid-execution instead of staying live
-    # alongside the scan working set
-    return jax.jit(vmapped, donate_argnums=(1,) if static.donate else ())
+            mesh = Mesh(
+                np.asarray(jax.devices()[: static.n_devices]), axis_names=("seeds",)
+            )
+            # seeds never communicate: no collective to check, and the scan's
+            # replicated initial carry need not be cast to the varying axis
+            vmapped = jax.shard_map(
+                vmapped,
+                mesh=mesh,
+                in_specs=(PartitionSpec(), PartitionSpec("seeds")),
+                out_specs=PartitionSpec("seeds"),
+                check_vma=False,
+            )
+        # donate the tape: slot-shaped outputs alias the input buffers and
+        # consumed tape buffers free mid-execution instead of staying live
+        # alongside the scan working set
+        return jax.jit(vmapped, donate_argnums=(1,) if static.donate else ())
 
 
 def _payload_bytes(payload_elems: int) -> int:
@@ -956,13 +965,8 @@ def _resolve_program(
         n_devices=n_devices,
         donate=bool(donate),
     )
-    tstatic = _TableStatic(
-        mode=table.mode,
-        mechanism=table.mechanism,
-        ckpt_invalidation=bool(table.ckpt_invalidation),
-    )
     with x64():  # program construction traces x64 constants
-        fn = _compiled_replayer(static, tstatic)
+        fn = _compiled_replayer(static)
     args = (_table_coeffs(table), tape)
     ctx = {"table": table, "rules_agent_small": static.rules_agent_small}
     return fn, args, det, verdicts, ctx
@@ -1045,9 +1049,9 @@ def replay_batch(
     detector. Returns per-seed numpy arrays keyed like
     :class:`~repro.scenarios.engine.CampaignResult` fields (``total_s`` /
     ``failed_at_s`` are NaN where inapplicable). One jitted vmapped
-    program evaluates every seed; programs are cached per
-    (scenario-shape, cost-table) pair, so repeated calls only pay the
-    fold itself.
+    program evaluates every seed; programs are cached per scenario
+    shape (the cost table is a runtime argument), so repeated calls —
+    under any strategy — only pay the fold itself.
 
     ``record_slots=True`` additionally returns per-slot decision arrays
     (``slot_processed`` / ``slot_handled`` / ``slot_victim`` /

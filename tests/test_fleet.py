@@ -6,7 +6,7 @@ must never change a single bit of any replay output — padding slots are
 provable no-ops (t=inf, valid=False), seed shards are independent, and
 donation only recycles input storage. These property tests pin that
 contract, plus the cost-table-coefficient program cache (one XLA compile
-serves every strategy sharing a structural table shape) and the
+serves every strategy on one scenario shape) and the
 compacted partition tape (width-1 placeholder when no cut opens, so the
 tape stays O(events + nodes) at fleet sizes).
 
@@ -188,7 +188,7 @@ def test_fleet_kernel_matches_engine(fleet_spec, fleet_batch, fleet_micro, strat
 def test_cost_table_values_share_one_program(fleet_spec, fleet_batch, fleet_micro):
     """Cost-table *values* are traced arguments, not compile-time
     constants: replaying the same strategy under two workloads' cost
-    tables (same structural flags, different numbers) must hit the
+    tables (same flags, different numbers) must hit the
     program cache, not lower a second XLA program."""
     replay_batch(fleet_spec, fleet_batch, "central_single", workload="analytic")
     s1 = replay_cache_stats()

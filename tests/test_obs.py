@@ -321,7 +321,8 @@ out = child.decide("repro.orchestrator.plan:choose_strategy", registry.get("tabl
 print(json.dumps({"call_s": out["call_s"]}))
 """
 _SPAN_READERS = ("micro_calibration_s", "tape_compile_s", "verdict_tapes_s", "frames_s",
-                 "jit_trace_lower_s", "xla_compile_s", "untraced_call_share")
+                 "jit_trace_lower_s", "xla_compile_s", "untraced_call_share",
+                 "replay_programs_built")
 
 
 def test_traced_oracle_call_has_leaf_spans(tmp_path, monkeypatch):
@@ -351,7 +352,8 @@ def test_traced_oracle_call_has_leaf_spans(tmp_path, monkeypatch):
     for name, _, _ in host:
         if name.startswith("repro."):
             counts[name] = counts.get(name, 0) + 1
-    assert counts == {"repro.micro": 1, "repro.tapes": 1, "repro.verdicts": 2, "repro.frames": 2}
+    assert counts == {"repro.micro": 1, "repro.tapes": 1, "repro.verdicts": 2, "repro.frames": 2,
+                      "repro.replay_program": 1}
     leaves = [h for h in host if h[0].startswith("repro.")]
     inner = leaves + [h for h in host if h[0].startswith("PjitFunction(")]
     assert any(h[0] == "PjitFunction(one_seed)" for h in inner)
@@ -365,6 +367,100 @@ def test_traced_oracle_call_has_leaf_spans(tmp_path, monkeypatch):
         assert value is not None and math.isfinite(value), reader
     assert load_reader("xla_compile_s")(run) > 0
     assert 0 <= load_reader("untraced_call_share")(run) < 0.25
+    assert load_reader("replay_programs_built")(run) == 1.0
+
+
+# two decisions over the benchmark's four candidates in one process, each
+# traced on its own: the first builds the one replay program, the second
+# finds it in the cache
+_TWO_DECISIONS = """
+import sys
+from chipbench import child
+from repro.scenarios import registry
+for seed, trace_dir in ((0, sys.argv[1]), (1000, sys.argv[2])):
+    request = {"candidates": ("central_single", "agent", "core", "hybrid"), "n_seeds": 8,
+               "seed": seed, "detector": "ewma_straggler", "workload": "analytic"}
+    child.decide("repro.orchestrator.plan:choose_strategy", registry.get("table2_random"),
+                 request, trace_dir)
+"""
+
+
+def test_decision_builds_one_replay_program(tmp_path, monkeypatch):
+    """The four candidates of a decision share one replay program: the
+    first decision of a process records one ``repro.replay_program``
+    span, the second, on a warm cache, none."""
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parents[1]
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join([str(root), str(root / "src")]))
+    dirs = [str(tmp_path / "first"), str(tmp_path / "second")]
+    proc = subprocess.run([sys.executable, "-c", _TWO_DECISIONS, *dirs], cwd=root,
+                          env=env, capture_output=True, text=True, timeout=240)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+
+    monkeypatch.syspath_prepend(str(root))
+    from chipbench import spans, tracefile
+    from chipbench.run import load_reader
+
+    built = []
+    for d in dirs:
+        trace = tracefile.load(d)
+        # JAX nests two events of the name in each call: four calls
+        assert len(spans.intervals(trace, lambda n: n == "PjitFunction(one_seed)")) == 4
+        built.append(sum(h[0] == "repro.replay_program" for h in trace["host"]))
+        run = {"trace": trace, "call_s": [1.0]}
+        assert load_reader("replay_programs_built")(run) == (1.0 if len(built) == 1 else None)
+    assert built == [1, 0]
+
+
+def _span_run(host, decisions=1):
+    trace = {"window": [0, 1000], "devices": {}, "host": [list(h) for h in host]}
+    return {"trace": trace, "call_s": [1.0] * decisions}
+
+
+@pytest.mark.parametrize(
+    "run, want",
+    [
+        # one program built in one traced decision
+        (_span_run([("chipbench_request", 0, 1000), ("repro.replay_program", 100, 5)]), 1.0),
+        # the mean over the traced decisions
+        (_span_run([("repro.replay_program", 100, 5), ("repro.replay_program", 600, 5),
+                    ("repro.replay_program", 700, 5)], decisions=2), 1.5),
+        # a program without the span (an older tree) reads nothing
+        (_span_run([("chipbench_request", 0, 1000), ("PjitFunction(one_seed)", 100, 50)]), None),
+        # no trace, or no decision in it
+        ({"trace": None, "call_s": [1.0]}, None),
+        (_span_run([("repro.replay_program", 100, 5)], decisions=0), None),
+    ],
+)
+def test_replay_programs_built_reader(run, want, monkeypatch):
+    from pathlib import Path
+
+    monkeypatch.syspath_prepend(str(Path(__file__).resolve().parents[1]))
+    from chipbench.run import load_reader
+
+    assert load_reader("replay_programs_built")(run) == want
+
+
+def test_replay_programs_built_reads_nothing_before_the_span(monkeypatch):
+    """The decision recorded on a TPU v5e before the span existed (four
+    replay programs, no ``repro.replay_program``) reads null."""
+    import gzip
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parents[1]
+    monkeypatch.syspath_prepend(str(root))
+    from chipbench import spans
+    from chipbench.run import load_reader
+
+    with gzip.open(root / "chipbench" / "tests" / "recorded_spans_trace.json.gz", "rt") as f:
+        trace = json.load(f)
+    run = {"trace": trace, "call_s": [trace["window"][1] / 1e9]}
+    assert len(spans.intervals(trace, lambda n: n == "PjitFunction(one_seed)")) == 4
+    assert load_reader("replay_programs_built")(run) is None
 
 
 def test_measured_step_surface_mapping():

@@ -14,10 +14,12 @@ from repro.scenarios.engine import CampaignEngine
 from repro.scenarios.montecarlo import params_from_scenario
 from repro.scenarios.spec import FailureProcessSpec, ScenarioSpec
 from repro.scenarios.trajectory import (
+    _compiled_replayer,
     _quiet_donation,
     compile_batch,
     compile_tape,
     replay_batch,
+    replay_cache_stats,
     replay_program,
 )
 from repro.utils.backend import x64
@@ -60,7 +62,8 @@ N_DIFF_SEEDS = 10
 
 
 def assert_trials_match(spec, strategy, n_seeds, micro, placement=None):
-    """Every kernel trial equals the engine run for the same seed."""
+    """Every kernel trial equals the engine run for the same seed; returns
+    the kernel's outputs."""
     batch = compile_batch(spec, n_seeds)
     out = replay_batch(spec, batch, strategy, micro=micro, placement=placement)
     for k in range(n_seeds):
@@ -86,6 +89,7 @@ def assert_trials_match(spec, strategy, n_seeds, micro, placement=None):
         else:
             assert np.isnan(out["total_s"][k])
             assert out["failed_at_s"][k] == pytest.approx(r.failed_at_s, rel=1e-12)
+    return out
 
 
 # ------------------------------------------------- differential: families ---
@@ -338,3 +342,122 @@ def test_replay_program_module_is_named_jit_one_seed(micro):
     with x64(), _quiet_donation():
         text = fn.lower(*args).as_text()
     assert text.split(None, 2)[:2] == ["module", "@jit_one_seed"]
+
+
+# ------------------------------------------- one program per scenario shape ----
+#: every billing branch of the replay kernel: window with checkpoint
+#: invalidation, window without it (a custom reactive strategy's default
+#: table), proactive agent / core / rules, cold
+_ONE_PROGRAM_STRATEGIES = (
+    "central_single", "custom_traj_window", "agent", "core", "hybrid", "cold_restart",
+)
+
+
+def test_one_program_serves_every_cost_table():
+    """The cost table's branch flags travel as runtime values: six
+    strategies on one scenario shape build one program (one miss, five
+    hits), each still matches the engine trial for trial, and replaying
+    them again in reverse order gives bitwise the first pass's outputs —
+    no branch of an earlier table is left over in a later one."""
+    from repro.strategies import FaultToleranceStrategy, StrategyCosts
+    from repro.strategies.base import FailureOutcome
+    from repro.strategies.registry import register, unregister
+
+    @register("custom_traj_window")
+    class CustomWindow(FaultToleranceStrategy):
+        """Restores onto the target, billed by the default cost table."""
+
+        def costs(self, ctx):
+            return StrategyCosts(predict_s=0.0, reinstate_s=11.0, overhead_s=7.0)
+
+        def on_failure(self, event, target):
+            rt = self.rt
+            shard = rt.hosts[event.node].shard
+            rt.release(event.node)
+            rt.occupy(target, shard, f"{self.name}:{event.node}")
+            rt.graph.remap(event.node, target)
+            t = float(event.t)
+            return FailureOutcome(
+                new_host=int(target), lost_s=t - self._window_start(t),
+                reinstate_s=11.0, overhead_s=7.0, outcome="restored",
+            )
+
+    # failures inside checkpoint cuts (invalidation) and a predictable
+    # failure of the star's hub, Z = 11 > 10 (the hybrid picks the agent)
+    spec = ScenarioSpec(
+        name="one_program_traj",
+        n_nodes=12,
+        n_spares=2,
+        horizon_s=3 * 3600.0,
+        processes=[
+            FailureProcessSpec("ckpt_window", {"offset_s": 5.0}),
+            FailureProcessSpec(
+                "cascade", {"node": 11, "t": 600.0, "depth": 1, "delay_s": 300.0, "predictable": True}
+            ),
+        ],
+        repair_s=900.0,
+    )
+    m = micro_for(spec.n_nodes)
+    try:
+        _compiled_replayer.cache_clear()
+        first = {s: assert_trials_match(spec, s, N_DIFF_SEEDS, m) for s in _ONE_PROGRAM_STRATEGIES}
+        assert replay_cache_stats() == {"hits": 5, "misses": 1, "programs": 1}
+        # the six tables bill six different ways
+        totals = {out["total_s"].tobytes() for out in first.values()}
+        assert len(totals) == len(_ONE_PROGRAM_STRATEGIES)
+
+        batch = compile_batch(spec, N_DIFF_SEEDS)
+        for s in reversed(_ONE_PROGRAM_STRATEGIES):
+            again = replay_batch(spec, batch, s, micro=m)
+            assert again.keys() == first[s].keys()
+            for key, a in first[s].items():
+                assert np.array_equal(a, again[key], equal_nan=True), (s, key)
+        assert replay_cache_stats()["misses"] == 1
+    finally:
+        unregister("custom_traj_window")
+
+
+@pytest.mark.parametrize(
+    "first, second",
+    [
+        (dict(record_slots=True), dict(record_slots=False)),
+        (dict(placement="partition-aware"), dict(placement="nearest-spare")),
+    ],
+    ids=["record_slots", "partition_aware"],
+)
+def test_program_shapes_stay_apart(micro, first, second):
+    """What changes the program's structure — recording per-slot
+    decisions, partition-scoped placement over an open cut — still builds
+    a program of its own; a second strategy on either shape hits it."""
+    spec = registry.get("partition_split")
+    batch = compile_batch(spec, 4)
+    m = micro_for(spec.n_nodes)
+    _compiled_replayer.cache_clear()
+    replay_batch(spec, batch, "core", micro=m, **first)
+    replay_batch(spec, batch, "core", micro=m, **second)
+    assert replay_cache_stats()["misses"] == 2
+    replay_batch(spec, batch, "central_single", micro=m, **first)
+    replay_batch(spec, batch, "hybrid", micro=m, **second)
+    assert replay_cache_stats() == {"hits": 2, "misses": 2, "programs": 2}
+
+
+def test_replay_rejects_unknown_billing_mode(micro):
+    """A cost table whose mode the kernel cannot bill is refused, not
+    billed under another mode's clock."""
+    from repro.strategies import FaultToleranceStrategy, StrategyCostTable
+
+    class Odd(FaultToleranceStrategy):
+        name = "odd_mode_traj_test"
+
+        def costs(self, ctx):  # pragma: no cover - unused
+            raise NotImplementedError
+
+        def cost_table(self, ctx):
+            return StrategyCostTable(mode="teleport")
+
+        def on_failure(self, event, target):  # pragma: no cover - unused
+            raise NotImplementedError
+
+    spec = registry.get("table2_random")
+    with pytest.raises(ValueError, match="teleport"):
+        replay_batch(spec, compile_batch(spec, 2), Odd(), micro=micro)
