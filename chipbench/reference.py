@@ -1,4 +1,5 @@
-"""The plain reference of the planning oracle's answer.
+"""The plain reference of the planning oracle's answer: shared pieces and
+their default composition.
 
 A straightforward, one-trial-at-a-time restatement of what
 ``choose_strategy(spec, candidates, n_seeds=N, seed=base)`` must return for
@@ -9,6 +10,22 @@ registered families document), each trial is folded through plain Python
 state (health, blacklist, spare pool, occupancy, repairs), billed per
 strategy, and reduced to the oracle's scores and winner.
 
+Every configuration names its reference: ``"reference"`` in
+``configs/<name>.json`` is a module under ``chipbench/`` (dotted, as
+``tests.periodic_reference``) whose
+``decide(spec, candidates, n_seeds, base_seed, reinstate_s, detector=...)``
+``run.py`` calls for each answer of the window. This module is the default
+(``"reference": "reference"``). A configuration that needs more brings a
+module of its own that imports the pieces here and adds only what is new,
+each passed to :func:`decide` as an argument:
+
+- a failure process kind: a draw ``(spec, params, rng, seed, idx) ->
+  [(t, node)]`` added to :data:`KINDS`, as
+  ``stream=functools.partial(failure_stream, kinds={**KINDS, "weibull": draw})``;
+- a repair distribution: ``repair=`` a function of the trial's repair
+  generator that returns one repair's seconds;
+- a workload's per-node state: ``state_bytes={**STATE_BYTES, name: bytes}``.
+
 Every per-event cost is derived here from the published figures of the
 paper's cluster (:func:`billing_costs`), save two: the agent's and the
 core's reinstate times, which the system measures by wall clock once per
@@ -18,17 +35,20 @@ process. Those two the run hands over, from the process that answered.
 ``np.float64`` is the reference, ``np.float32`` the control that has to be
 caught by the comparison.
 
-Supported: the failure processes ``random``, ``rack``, ``burst``, ``flaky``
-and ``degrade``; constant repairs or none; placement ``nearest-spare`` with
-no co-hosting; the ``ewma_straggler`` detector (it claims no failure, so
-every failure is handled blind, and it flags stragglers); the strategies
-``central_single``, ``agent``, ``core`` and ``hybrid``. A configuration
-outside that set raises ``NotImplementedError`` or ``KeyError``.
+Supported here: the failure processes ``random``, ``rack``, ``burst``,
+``flaky`` and ``degrade``; repairs constant, ``("lognormal", mu, sigma)``
+(every repair distribution the program offers) or none; placement
+``nearest-spare`` with no co-hosting; the ``ewma_straggler`` detector (it
+claims no failure, so every failure is handled blind, and it flags
+stragglers); the strategies ``central_single``, ``agent``, ``core`` and
+``hybrid``; the ``analytic`` workload's state. A configuration outside that
+set, and not brought in by its own module, raises ``NotImplementedError``
+or ``KeyError``.
 """
 from __future__ import annotations
 
 import math
-from typing import Dict, List, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -68,10 +88,12 @@ def _racks(spec: Dict) -> Dict[int, int]:
     return {i: i % 2 for i in range(spec["n_nodes"])}
 
 
-def _random_model(spec: Dict, p: Dict, seed: int) -> List[Tuple]:
+def _random(spec: Dict, p: Dict, rng, seed: int, idx: int) -> List[Tuple]:
     """The paper's random pattern: per hourly window, ``per_window``
-    failures at uniform instants on uniform nodes."""
-    rng = np.random.default_rng(seed)
+    failures at uniform instants on uniform nodes, from a generator of its
+    own (the first ``random`` process on the trial's seed, so the paper's
+    tables hold; repeats on seeds derived from it)."""
+    rng = np.random.default_rng(p.get("seed", seed + 1_000_003 * idx))
     horizon, n_nodes = spec["horizon_s"], spec["n_nodes"]
     period = p.get("period_s", spec["period_s"])
     per_window = p.get("per_window", 1)
@@ -88,55 +110,104 @@ def _random_model(spec: Dict, p: Dict, seed: int) -> List[Tuple]:
     return sorted(out, key=lambda e: e[0])
 
 
-def failure_stream(spec: Dict, seed: int) -> List[Tuple[float, int]]:
-    """``(t, node)`` of every failure of one trial, in time order (ties keep
-    the process order). Each failure's predictability is drawn, to keep the
-    generators' order, and dropped: no supported detector reads it."""
+def _burst(spec: Dict, p: Dict, rng, seed: int, idx: int) -> List[Tuple]:
     n_nodes = spec["n_nodes"]
+    t = float(p.get("t", spec["period_s"] / 2))
+    k = int(p.get("k", min(3, n_nodes)))
+    out = []
+    for j, n in enumerate(rng.choice(n_nodes, size=min(k, n_nodes), replace=False)):
+        rng.random()
+        out.append((t + 1e-3 * j, int(n)))
+    return out
+
+
+def _rack(spec: Dict, p: Dict, rng, seed: int, idx: int) -> List[Tuple]:
+    racks = _racks(spec)
+    rack = p.get("rack")
+    if rack is None:
+        rack = int(rng.choice(sorted(set(racks.values()))))
+    t0 = float(p.get("t", spec["period_s"] / 2))
+    spread = float(p.get("spread_s", 60.0))
+    out = []
+    for n in [n for n, r in racks.items() if r == rack and n < spec["n_nodes"]]:
+        t = t0 + float(rng.uniform(0.0, spread))
+        rng.random()
+        out.append((t, int(n)))
+    return out
+
+
+def _flaky(spec: Dict, p: Dict, rng, seed: int, idx: int) -> List[Tuple]:
+    node = int(p.get("node", rng.integers(0, spec["n_nodes"])))
+    every = float(p.get("every_s", spec["period_s"] / 2))
+    t = float(p.get("first_t", every))
+    out = []
+    while t < spec["horizon_s"]:
+        rng.random()
+        out.append((t, node))
+        t += every
+    return out
+
+
+def _degrade(spec: Dict, p: Dict, rng, seed: int, idx: int) -> List[Tuple]:
+    return []  # no failure: a slow node, billed by slowdown_s
+
+
+#: process kind -> draw ``(spec, params, rng, seed, idx) -> [(t, node)]``:
+#: ``rng`` is the process's generator, ``default_rng((seed, i))`` for the
+#: ``i``-th process of the list; ``seed`` the trial's; ``idx`` counts the
+#: earlier processes of the same kind
+KINDS: Dict[str, Callable] = {
+    "random": _random, "burst": _burst, "rack": _rack, "flaky": _flaky, "degrade": _degrade,
+}
+
+
+def failure_stream(spec: Dict, seed: int, kinds: Dict[str, Callable] = KINDS) -> List[Tuple[float, int]]:
+    """``(t, node)`` of every failure of one trial, in time order (ties keep
+    the process order), each process drawn by its kind's entry in ``kinds``.
+    Each failure's predictability is drawn, to keep the generators' order,
+    and dropped: no supported detector reads it."""
     out: List[Tuple] = []
     occurrence: Dict[str, int] = {}
     for i, proc in enumerate(spec["processes"]):
-        kind, p = proc["kind"], proc.get("params", {})
-        rng = np.random.default_rng((seed, i))
+        kind = proc["kind"]
+        if kind not in kinds:
+            raise NotImplementedError(f"failure process {kind!r}")
         idx = occurrence.get(kind, 0)
         occurrence[kind] = idx + 1
-        if kind == "random":
-            out += _random_model(spec, p, p.get("seed", seed + 1_000_003 * idx))
-        elif kind == "burst":
-            t = float(p.get("t", spec["period_s"] / 2))
-            k = int(p.get("k", min(3, n_nodes)))
-            nodes = rng.choice(n_nodes, size=min(k, n_nodes), replace=False)
-            for j, n in enumerate(nodes):
-                rng.random()
-                out.append((t + 1e-3 * j, int(n)))
-        elif kind == "rack":
-            racks = _racks(spec)
-            rack = p.get("rack")
-            if rack is None:
-                rack = int(rng.choice(sorted(set(racks.values()))))
-            t0 = float(p.get("t", spec["period_s"] / 2))
-            spread = float(p.get("spread_s", 60.0))
-            for n in [n for n, r in racks.items() if r == rack and n < n_nodes]:
-                t = t0 + float(rng.uniform(0.0, spread))
-                rng.random()
-                out.append((t, int(n)))
-        elif kind == "flaky":
-            node = int(p.get("node", rng.integers(0, n_nodes)))
-            every = float(p.get("every_s", spec["period_s"] / 2))
-            t = float(p.get("first_t", every))
-            while t < spec["horizon_s"]:
-                rng.random()
-                out.append((t, node))
-                t += every
-        elif kind != "degrade":
-            raise NotImplementedError(f"failure process {kind!r}")
+        out += kinds[kind](spec, proc.get("params", {}), np.random.default_rng((seed, i)), seed, idx)
     out = [e for e in out if e[0] < spec["horizon_s"]]
     return sorted(out, key=lambda e: e[0])
 
 
+def spec_repair(spec: Dict) -> Optional[Callable]:
+    """``repair(rng) -> seconds`` of the spec's ``repair_s``, or None where
+    failed hosts never return: the constant, or ``("lognormal", mu, sigma)``,
+    one draw of numpy's ``Generator.lognormal(mu, sigma)`` (the spec's
+    documented distribution: the log of a repair's seconds is normal with
+    mean ``mu`` and deviation ``sigma``)."""
+    r = spec.get("repair_s")
+    if r is None:
+        return None
+    if isinstance(r, (list, tuple)):
+        kind, mu, sigma = r
+        if kind != "lognormal":
+            raise NotImplementedError(f"repair distribution {kind!r}")
+        return lambda rng: float(rng.lognormal(float(mu), float(sigma)))
+    return lambda rng: float(r)
+
+
 # --------------------------------------------------------- one campaign ---
-def campaign(spec: Dict, seed: int) -> Dict:
-    """Fold one trial's failures through the cluster's control state.
+def campaign(spec: Dict, seed: int, stream: Callable = failure_stream,
+             repair: Optional[Callable] = None) -> Dict:
+    """Fold one trial's failures, ``stream(spec, seed)``, through the
+    cluster's control state.
+
+    A failed host returns after ``repair(rng)`` seconds (default: the
+    spec's ``repair_s``, :func:`spec_repair`), unless the spec's
+    ``repair_s`` is None or the host has struck out. ``rng`` is the trial's
+    repair generator, ``default_rng((seed, 0x5EED))``, and ``repair`` is
+    called once for each repair the fold schedules, in the fold's order:
+    the order in which the program consumes its pre-drawn repairs.
 
     Placement is strategy-independent (every supported strategy moves the
     failed host's sub-job to the first free healthy spare, else a free
@@ -148,9 +219,10 @@ def campaign(spec: Dict, seed: int) -> Dict:
         raise NotImplementedError(f"placement {spec['placement']!r}")
     n_nodes, H = spec["n_nodes"], spec["n_nodes"] + spec["n_spares"]
     horizon = spec["horizon_s"]
-    repair = spec.get("repair_s")
-    if isinstance(repair, (list, tuple)):
-        raise NotImplementedError(f"repair distribution {repair[0]!r}")
+    never_repaired = spec.get("repair_s") is None
+    if repair is None:
+        repair = spec_repair(spec)
+    repair_rng = np.random.default_rng((seed, 0x5EED))
 
     healthy = [True] * H
     black = [False] * H
@@ -190,7 +262,7 @@ def campaign(spec: Dict, seed: int) -> Dict:
                 return h
         return None
 
-    for t, host in failure_stream(spec, seed):
+    for t, host in stream(spec, seed):
         for h, tr in sorted(pending.items(), key=lambda kv: (kv[1], kv[0])):
             if tr < t:
                 del pending[h]
@@ -199,7 +271,7 @@ def campaign(spec: Dict, seed: int) -> Dict:
         if not healthy[host]:
             continue
         strikes[host] += 1
-        permanent = repair is None or strikes[host] >= spec["max_strikes"]
+        permanent = never_repaired or strikes[host] >= spec["max_strikes"]
         if work[host] >= 0:
             target = pick(host)
             if target is None:
@@ -217,7 +289,7 @@ def campaign(spec: Dict, seed: int) -> Dict:
             black[host] = True
             out["n_blacklisted"] += 1
         else:
-            pending[host] = t + float(repair)
+            pending[host] = t + repair(repair_rng)
     out["n_handled"] = len(out["handled"])
     if out["survived"]:
         for h, tr in sorted(pending.items(), key=lambda kv: (kv[1], kv[0])):
@@ -227,15 +299,17 @@ def campaign(spec: Dict, seed: int) -> Dict:
 
 
 # -------------------------------------------------------------- billing ---
-def billing_costs(spec: Dict, reinstate_s: Dict[str, float]) -> Dict:
+def billing_costs(spec: Dict, reinstate_s: Dict[str, float],
+                  state_bytes: Dict[str, int] = STATE_BYTES) -> Dict:
     """The per-failure costs of the paper's cost model on its cluster:
     the central checkpoint server's write (overhead) and restore
     (reinstate) of every other node's data, and each proactive mechanism's
     log mining, staging and respawn. ``reinstate_s`` holds the measured
-    ``agent`` and ``core`` reinstate seconds."""
+    ``agent`` and ``core`` reinstate seconds; ``state_bytes`` each
+    workload's data per node."""
     p = PLACENTIA
     n = spec["n_nodes"]
-    s_d = STATE_BYTES[spec["workload"]]
+    s_d = state_bytes[spec["workload"]]
     speed = max(p["node_speed"], 0.1)
     staging = s_d / p["node_bw"]
     total = s_d * max(n - 1, 1)
@@ -346,16 +420,21 @@ def bill(spec: Dict, trial: Dict, strategy: str, costs: Dict, slow: float,
 
 # ----------------------------------------------------------- the answer ---
 def decide(spec: Dict, candidates: Sequence[str], n_seeds: int, base_seed: int,
-           costs: Dict, detector: str = "ewma_straggler", dtype=np.float64):
+           reinstate_s: Dict[str, float], detector: str = "ewma_straggler",
+           dtype=np.float64, stream: Callable = failure_stream,
+           repair: Optional[Callable] = None, state_bytes: Dict[str, int] = STATE_BYTES):
     """The oracle's ``(winner, scores)`` for seeds ``base_seed ..
     base_seed + n_seeds - 1``: survival rate, then the mean and 95th
     percentile of the surviving trials' makespans; the winner has the best
     survival and, among those, the lowest mean (first in candidate order on
-    a tie)."""
+    a tie). ``reinstate_s`` holds the two measured reinstate seconds;
+    ``stream``, ``repair`` and ``state_bytes`` are as :func:`campaign` and
+    :func:`billing_costs` take them."""
     if detector != "ewma_straggler":
         raise NotImplementedError(f"detector {detector!r}")
+    costs = billing_costs(spec, reinstate_s, state_bytes)
     slow = slowdown_s(spec, mitigate=True)
-    trials = [campaign(spec, base_seed + s) for s in range(n_seeds)]
+    trials = [campaign(spec, base_seed + s, stream, repair) for s in range(n_seeds)]
     scores = {}
     for name in candidates:
         billed = [bill(spec, tr, name, costs, slow, dtype) for tr in trials]

@@ -22,9 +22,13 @@ chip is the child's. A run
 3. measures: one caller in a closed loop, issuing requests while the clock
    is inside ``--seconds`` and waiting for each answer; the window closes
    with the last answer. With ``--trace 1`` each child traces its call;
-4. compares every answer of the window with the plain reference
-   (:mod:`chipbench.reference`), and prints each compared number beside its
-   limit as the last lines of standard error;
+4. compares every answer of the window with the plain reference that the
+   configuration names (``"reference"`` in its file: a module under
+   ``chipbench/``, :mod:`chipbench.reference` or one composed from its
+   pieces, which brings the configuration's own failure process kinds,
+   repair distribution or workload state; a configuration that names none
+   is refused), and prints each compared number beside its limit as the
+   last lines of standard error;
 5. prints one JSON object as the last line of standard output: ``correct``,
    ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end metrics, or
    with ``--trace 1`` its per-layer ones, each read by ``metrics/<name>.py``),
@@ -81,6 +85,16 @@ def load_reader(name: str):
     return module.read
 
 
+def load_reference(configuration: Dict):
+    """The plain reference the configuration names: the module
+    ``chipbench.<reference>``, whose ``decide`` answers as the oracle must."""
+    name = configuration.get("reference")
+    if name is None:
+        raise SystemExit(f"configuration {configuration['name']!r} names no reference; give it "
+                         '"reference": "<module under chipbench/>" ("reference": the default)')
+    return importlib.import_module(f"chipbench.{name}")
+
+
 def read_metrics(cell: Dict, kind: str, run: Dict) -> Dict:
     out = {}
     for m in cell["bench"][kind]:
@@ -95,10 +109,11 @@ def read_metrics(cell: Dict, kind: str, run: Dict) -> Dict:
 def run_cell(cell: Dict, seed: int, seconds: float, trace: bool, look_for_chip: bool = True):
     """Set up, measure and check one run; returns ``(result, checks)``.
     ``look_for_chip=False`` skips the device check, for tests on the CPU."""
-    from chipbench import check, child, loadgen, reference, tracefile
+    from chipbench import check, child, loadgen, tracefile
     from repro.orchestrator.plan import plan_in_child
     from repro.scenarios.spec import ScenarioSpec
 
+    reference = load_reference(cell["configuration"])
     mix, spec_dict = cell["mix"], cell["configuration"]["spec"]
     loadgen.validate(mix)
     spec = ScenarioSpec.from_dict(spec_dict)
@@ -137,9 +152,8 @@ def run_cell(cell: Dict, seed: int, seconds: float, trace: bool, look_for_chip: 
                       if trace and answered else None)
 
     pairs = [
-        (out["answer"], reference.decide(
-            spec_dict, mix["candidates"], mix["n_seeds"], base,
-            reference.billing_costs(spec_dict, out["reinstate_s"]), detector=mix["detector"]))
+        (out["answer"], reference.decide(spec_dict, mix["candidates"], mix["n_seeds"], base,
+                                         out["reinstate_s"], detector=mix["detector"]))
         for base, out, _ in answered
     ]
     correct, checks = check.verdict(check.compare(pairs, failed))

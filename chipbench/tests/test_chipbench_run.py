@@ -1,5 +1,7 @@
 """The command refuses to measure anywhere but on the TPU the cell asks
-for: it exits non-zero and prints no result line."""
+for: it exits non-zero and prints no result line. It compares with the
+reference that the configuration names, and refuses a configuration that
+names none."""
 import json
 import os
 import subprocess
@@ -11,6 +13,7 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
+from chipbench import run  # noqa: E402
 from chipbench.child import device_refusal  # noqa: E402
 
 KINDS = json.loads((ROOT / "chipbench" / "devices.json").read_text())["kinds"]
@@ -52,3 +55,26 @@ def test_device_refusal(device, chips, refused):
         assert why is None
     else:
         assert refused in why
+
+
+def _cell(**configuration):
+    cell = run.load_cell("table2_random.child200")
+    cell["configuration"] = {k: v for k, v in dict(cell["configuration"], **configuration).items()
+                             if v is not None}
+    return cell
+
+
+@pytest.mark.parametrize("name, correct", [("reference", True), ("tests.planted_reference", False)])
+def test_run_compares_with_the_reference_the_configuration_names(name, correct):
+    """The sound program against the default reference, then against one
+    that names a wrong winner: only the configuration's key differs."""
+    result, checks = run.run_cell(_cell(reference=name), 3_000_000_011, 0.3, False,
+                                  look_for_chip=False)
+    assert result["correct"] is correct, checks
+    assert result["failed"] == 0
+    assert (checks["winner_mismatch"]["value"] == 0) is correct
+
+
+def test_configuration_without_a_reference_is_refused():
+    with pytest.raises(SystemExit, match="names no reference"):
+        run.run_cell(_cell(reference=None), 1, 0.3, False, look_for_chip=False)
